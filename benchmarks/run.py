@@ -66,6 +66,7 @@ from repro.core.planner import (CommPlanner, TransferSpec,
 from repro.configs.espsoc_trafficgen import (CONSUMER_SWEEP, SIZE_SWEEP,
                                              BITWIDTH_SWEEP, DEST_SWEEP,
                                              MESH_SCALE_SWEEP)
+from repro.launch.cache import enable_compile_cache
 
 _ROWS = []
 
@@ -593,7 +594,6 @@ def comm_mode_bytes():
     # dryrun import sets XLA_FLAGS, but jax is already initialized here, so
     # the device count cannot change.)
     try:
-        from repro import compat
         from repro.configs import get_reduced
         from repro.configs.base import ShapeConfig
         from repro.launch.dryrun import build_comm_plan, lower_cell, make_flags
@@ -601,8 +601,8 @@ def comm_mode_bytes():
 
         n = len(jax.devices())
         grid = (n // 2, 2) if n >= 4 else (1, n)
-        mesh = compat.make_mesh(grid, ("data", "model"),
-                                axis_types=(compat.AxisType.Auto,) * 2)
+        mesh = jax.make_mesh(grid, ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         cfg = get_reduced("dbrx-132b")
         shape = ShapeConfig("bench", 128, 4 * grid[0], "train")
         t0 = time.perf_counter()
@@ -728,6 +728,7 @@ def main() -> None:
     ap.add_argument("--out", default="BENCH_noc.json")
     ap.add_argument("--baseline", default="")
     args = ap.parse_args()
+    enable_compile_cache()
 
     print("name,us_per_call,derived,spread")
     if args.fig6_check:

@@ -34,7 +34,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs import get_reduced
 from repro.core import socket as socket_mod
 from repro.runtime.engine import ServeEngine, poisson_trace
@@ -73,8 +72,8 @@ def main():
         "distinct prompts should decode distinct continuations"
 
     # ---- part 2: the same descriptor on a live 8-tile stage axis ---------
-    mesh = compat.make_mesh((8,), ("stage",),
-                            axis_types=(compat.AxisType.Auto,))
+    mesh = jax.make_mesh((8,), ("stage",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     writer = eng.make_stage_kv_writer("stage")
     # one admitted request's first-layer K prefix, as the burst payload
     leaf = jax.tree.leaves(eng.pools)[0]
@@ -87,7 +86,7 @@ def main():
         traces.append(1)            # trace-time only: counts retraces
         return writer(rows, ranks)
 
-    fn = jax.jit(compat.shard_map(
+    fn = jax.jit(jax.shard_map(
         burst, mesh=mesh, in_specs=(P("stage", None), P()),
         out_specs=P("stage", None), check_vma=False))
 

@@ -21,14 +21,13 @@ from typing import Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.core.comm import validate_p2p_totals, reblock
 
 
 def p2p_shift(x: jax.Array, axis_name: str, offset: int = 1) -> jax.Array:
     """Forward ``x`` from stage i to stage i+offset (ring) along
     ``axis_name``.  Must be called inside shard_map/pmap collective context."""
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     perm = [(i, (i + offset) % n) for i in range(n)]
     return jax.lax.ppermute(x, axis_name, perm)
 

@@ -326,7 +326,7 @@ class AcceleratorSocket:
 
     ``use_kernels=True`` enables the Pallas fast paths (multicast stream)
     when the payload satisfies the kernel's constraints; ``interpret``
-    is forwarded to the kernel (tests pass ``compat.interpret_params()``).
+    is forwarded to the kernel (tests pass ``interpret_params()``).
 
     ``retry`` binds a :class:`RetryPolicy`: the fallible kernel dispatch
     paths then walk the :data:`DEGRADATION_LADDER` (bounded retries per
@@ -675,10 +675,9 @@ class AcceleratorSocket:
         whether this site runs at all (its MEM alternative is a different
         dataflow the *caller* traces), so ``hint`` carries the caller's
         flag-driven mode when no plan is active."""
-        from repro import compat
         assert self.axis_name is not None, "exchange needs a stage axis"
         mode = self.resolve_mode(desc, hint)
-        n = compat.axis_size(self.axis_name)
+        n = jax.lax.axis_size(self.axis_name)
         nbytes = self._nbytes(x)
         word = desc.word_bytes or x.dtype.itemsize
         mem = mode is CommMode.MEM
@@ -729,9 +728,8 @@ class AcceleratorSocket:
         full-batch FFN) — the fallback rungs and the non-fusible path
         below."""
         assert self.axis_name is not None, "dispatch_expert_ffn needs an axis"
-        from repro import compat
         mode = self.resolve_mode(dispatch_desc, hint)
-        n = compat.axis_size(self.axis_name)
+        n = jax.lax.axis_size(self.axis_name)
         fusible = (mode is not CommMode.MEM and self.use_kernels and
                    dispatch_desc.fused_with is not None and
                    isinstance(n, int) and n > 1 and toks.ndim == 4 and
@@ -845,8 +843,7 @@ class AcceleratorSocket:
         the unfused lax path — always available, numerically identical."""
         if not self.use_kernels or desc.fused_with is None or x.ndim != 2:
             return False
-        from repro import compat
-        return isinstance(compat.axis_size(self.axis_name), int)
+        return isinstance(jax.lax.axis_size(self.axis_name), int)
 
     def _streamed_ok(self, desc: TransferDescriptor, x) -> bool:
         """Streamed-MEM preconditions: kernels enabled, 2-D payload, a
@@ -869,7 +866,6 @@ class AcceleratorSocket:
         encodes user 0, a P2P ring hop the user=1 unicast degeneracy, an
         MCAST verdict the full ring's destination list — and fold the C3
         fence in."""
-        from repro import compat
         mode = self.resolve_mode(desc, hint)
         nbytes = self._nbytes(x)
         word = desc.word_bytes or x.dtype.itemsize
@@ -878,7 +874,7 @@ class AcceleratorSocket:
         elif mode is CommMode.P2P:
             dests = (1,)
         else:
-            n = compat.axis_size(self.axis_name)
+            n = jax.lax.axis_size(self.axis_name)
             dests = (tuple(range(1, n))
                      if isinstance(n, int) and n > 1 else (1,))
         req = CommRequest(max(nbytes // word, 1), word, mode, dests=dests)
@@ -970,9 +966,8 @@ class AcceleratorSocket:
         mode."""
         assert self.axis_name is not None, \
             "matmul_reduce_scatter needs a stage axis"
-        from repro import compat
         mode, x, nbytes, instr = self._fused_site(desc, x, hint)
-        n = compat.axis_size(self.axis_name)
+        n = jax.lax.axis_size(self.axis_name)
         divisible = isinstance(n, int) and x.shape[0] % n == 0
         if mode is CommMode.P2P and divisible and \
                 self._fused_ring_ok(desc, x):
@@ -1040,8 +1035,7 @@ class AcceleratorSocket:
         the descriptor excluded must not be on the path."""
         if not self.use_kernels or x.ndim != 2:
             return False
-        from repro import compat
-        n = compat.axis_size(self.axis_name)
+        n = jax.lax.axis_size(self.axis_name)
         if not isinstance(n, int):
             return False
         covers = len(set(ranks) - {src}) >= n - 1

@@ -21,7 +21,6 @@ from typing import Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 
 
 def flag_allreduce(flag: jax.Array, axis_name: str) -> jax.Array:
@@ -53,6 +52,6 @@ def ready_check(step_ok: jax.Array, axis_name: str) -> jax.Array:
     """Global 'every producer has produced' check before consumers proceed —
     the pull-request aggregation a multicast producer performs (it waits for
     N consumer requests before sending)."""
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     got = flag_allreduce(step_ok.astype(jnp.int32), axis_name)
     return got == n

@@ -1,10 +1,10 @@
 """Pallas TPU kernels for the communication hot spots.
 
 Each kernel has a pure-jnp oracle in ``ref.py`` and a jit'd public wrapper in
-``ops.py``; all are validated on CPU with ``compat.interpret_params()``
-(``pltpu.InterpretParams`` where available — simulating VMEM, DMA, remote
-copies, and semaphores — else the state-discharge interpreter; see
-``docs/compat.md`` for the uniform-DMA constraint the latter imposes).
+``ops.py``; all are validated on CPU with ``ops.interpret_params()``
+(``pltpu.InterpretParams``, which simulates VMEM, DMA, remote copies and
+semaphores) and compiled for a described TPU v5e topology by
+``tests/test_chip_compile.py``.
 
 Paper mapping:
   ring_allgather_matmul  — pull-based P2P forwarding (C1) fused with the MXU

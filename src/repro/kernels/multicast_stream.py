@@ -13,13 +13,12 @@ every device issues exactly one remote DMA per round.  At round r the
 device ``dist`` hops from the source forwards chunk ``c = r - dist``; when
 that chunk index is out of range (pipeline fill/drain) or the device is the
 last ring member, it still sends — into the receiver's scratch slot, so the
-payload is untouched.  Uniformity buys two things: per-round semaphores
-make the pipeline overrun-safe without per-device branching of the DMA
-sequence (the deadlock-freedom argument the paper inherits from [18]), and
-the kernel stays valid under the lockstep state-discharge interpreter of
-older JAX (``compat.UNIFORM_DMA_INTERPRET``), where every remote DMA is a
-collective all devices must issue and data advances one hop per round —
-exactly this schedule.
+payload is untouched.  Uniformity makes every device receive exactly one
+message per round, so per-round semaphores keep the pipeline overrun-safe
+without per-device branching of the DMA sequence (the deadlock-freedom
+argument the paper inherits from [18]).  The fill/drain sends into the
+scratch slot are wasted link traffic: a schedule that sends only real
+chunks would need per-device receive counts instead.
 
 Chunk granularity doubles as flow control: a member holds at most the one
 chunk it has not yet forwarded (the consumption assumption, C1).
@@ -34,13 +33,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
-
 
 def _mcast_kernel(axis_name, src, n_chunks, x_hbm, y_ref, buf, send_sems,
                   recv_sems, stage_sem):
     p = jax.lax.axis_index(axis_name)
-    P = compat.axis_size(axis_name)
+    P = jax.lax.axis_size(axis_name)
     right = jax.lax.rem(p + 1, P)
     dist = jax.lax.rem(p - src + P, P)      # hops from the source
     rows = y_ref.shape[0] // n_chunks
@@ -72,7 +69,7 @@ def _mcast_kernel(axis_name, src, n_chunks, x_hbm, y_ref, buf, send_sems,
         rc = pltpu.make_async_remote_copy(
             src_ref=buf.at[c_src], dst_ref=buf.at[dst_slot],
             send_sem=send_sems.at[r], recv_sem=recv_sems.at[r],
-            device_id=compat.remote_device_id(right),
+            device_id=(right,),
             device_id_type=pltpu.DeviceIdType.MESH)
         rc.start()
         rc.wait_send()
@@ -96,13 +93,13 @@ def multicast_stream_local(x, *, axis_name: str, src: int = 0,
     the source rank's value is used).  Returns (m, n) on every rank."""
     m, n = x.shape
     assert m % n_chunks == 0, f"rows {m} % chunks {n_chunks} != 0"
-    P = compat.axis_size(axis_name)
+    P = jax.lax.axis_size(axis_name)
     n_rounds = P + n_chunks - 1
     kernel = functools.partial(_mcast_kernel, axis_name, src, n_chunks)
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         scratch_shapes=[
             pltpu.VMEM((n_chunks + 1, m // n_chunks, n), x.dtype),
@@ -110,7 +107,6 @@ def multicast_stream_local(x, *, axis_name: str, src: int = 0,
             pltpu.SemaphoreType.DMA((n_rounds,)),
             pltpu.SemaphoreType.DMA,
         ],
-        compiler_params=compat.compiler_params(
-            collective_id=2, has_side_effects=True),
+        compiler_params=pltpu.CompilerParams(has_side_effects=True),
         interpret=interpret if interpret is not None else False,
     )(x)

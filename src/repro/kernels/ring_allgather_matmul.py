@@ -26,13 +26,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
-
 
 def _ag_mm_kernel(axis_name, x_hbm, w_ref, y_ref, gbuf, send_sems, recv_sems,
                   local_sem):
     p = jax.lax.axis_index(axis_name)
-    P = compat.axis_size(axis_name)
+    P = jax.lax.axis_size(axis_name)
     right = jax.lax.rem(p + 1, P)
     m = x_hbm.shape[0]
 
@@ -53,7 +51,7 @@ def _ag_mm_kernel(axis_name, x_hbm, w_ref, y_ref, gbuf, send_sems, recv_sems,
         rc = pltpu.make_async_remote_copy(
             src_ref=gbuf.at[cur], dst_ref=gbuf.at[cur],
             send_sem=send_sems.at[i], recv_sem=recv_sems.at[i],
-            device_id=compat.remote_device_id(right),
+            device_id=(right,),
             device_id_type=pltpu.DeviceIdType.MESH)
 
         @pl.when(i < P - 1)
@@ -76,7 +74,7 @@ def ring_allgather_matmul_local(x_local, w, *, axis_name: str,
                                 interpret=None):
     """Per-shard body (call inside shard_map).  x_local: (m, k) this rank's
     row shard; w: (k, n) replicated.  Returns (P*m, n) = full X @ W."""
-    P = compat.axis_size(axis_name)
+    P = jax.lax.axis_size(axis_name)
     m, k = x_local.shape
     n = w.shape[1]
     out_dtype = jnp.promote_types(x_local.dtype, w.dtype)
@@ -85,7 +83,7 @@ def ring_allgather_matmul_local(x_local, w, *, axis_name: str,
         kernel,
         out_shape=jax.ShapeDtypeStruct((P * m, n), out_dtype),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),    # x stays in HBM; DMA'd
+            pl.BlockSpec(memory_space=pl.ANY),       # x stays in HBM; DMA'd
             pl.BlockSpec(memory_space=pltpu.VMEM),   # w resident in VMEM
         ],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
@@ -95,7 +93,6 @@ def ring_allgather_matmul_local(x_local, w, *, axis_name: str,
             pltpu.SemaphoreType.DMA((P,)),           # per-step recv
             pltpu.SemaphoreType.DMA,                 # local staging
         ],
-        compiler_params=compat.compiler_params(
-            collective_id=0, has_side_effects=True),
+        compiler_params=pltpu.CompilerParams(has_side_effects=True),
         interpret=interpret if interpret is not None else False,
     )(x_local, w)

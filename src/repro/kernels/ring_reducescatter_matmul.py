@@ -21,13 +21,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
-
 
 def _rs_mm_kernel(axis_name, x_ref, w_ref, y_ref, send_buf, recv_buf,
                   send_sems, recv_sems):
     p = jax.lax.axis_index(axis_name)
-    P = compat.axis_size(axis_name)
+    P = jax.lax.axis_size(axis_name)
     right = jax.lax.rem(p + 1, P)
     mloc = y_ref.shape[0]
 
@@ -54,7 +52,7 @@ def _rs_mm_kernel(axis_name, x_ref, w_ref, y_ref, send_buf, recv_buf,
                 dst_ref=recv_buf.at[i + 1],
                 send_sem=send_sems.at[jax.lax.rem(i, 2)],
                 recv_sem=recv_sems.at[i + 1],
-                device_id=compat.remote_device_id(right),
+                device_id=(right,),
                 device_id_type=pltpu.DeviceIdType.MESH)
             rc.start()
             rc.wait_send()
@@ -71,7 +69,7 @@ def ring_reducescatter_matmul_local(x_local, w_local, *, axis_name: str,
                                     interpret=None):
     """Per-shard body (call inside shard_map).  x_local: (m, k_p), w_local:
     (k_p, n).  Returns (m/P, n): this rank's reduced output shard."""
-    P = compat.axis_size(axis_name)
+    P = jax.lax.axis_size(axis_name)
     m, kp = x_local.shape
     n = w_local.shape[1]
     assert m % P == 0
@@ -91,7 +89,6 @@ def ring_reducescatter_matmul_local(x_local, w_local, *, axis_name: str,
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((P,)),
         ],
-        compiler_params=compat.compiler_params(
-            collective_id=1, has_side_effects=True),
+        compiler_params=pltpu.CompilerParams(has_side_effects=True),
         interpret=interpret if interpret is not None else False,
     )(x_local, w_local)

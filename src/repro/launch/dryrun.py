@@ -28,7 +28,6 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs import (ARCH_NAMES, SHAPES, get_config, shape_applicable)
 from repro.configs.base import ArchConfig, ShapeConfig
 from repro.core.comm import CommMode
@@ -285,7 +284,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
                   f"{len(calib_flips)} plan flips)")
 
     ma = compiled.memory_analysis()
-    ma_peak = compat.peak_memory_in_bytes(ma)
+    ma_peak = ma.peak_memory_in_bytes
     mf = model_flops(cfg, shape)
     roof = hlo_analysis.analyze(compiled, model_flops_total=mf,
                                 n_chips=n_chips)

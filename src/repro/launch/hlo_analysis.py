@@ -47,8 +47,10 @@ _COMP_HDR = re.compile(r"^(ENTRY\s+)?(%[\w\.\-]+)\s*\(.*\)\s*->")
 _DEF_RE = re.compile(r"^\s*(?:ROOT\s+)?(%[\w\.\-]+)\s*=\s*(.*)$")
 _TYPE_RE = re.compile(r"^([a-z0-9]+)\[([\d,]*)\]")
 _TUPLE_TYPE_RE = re.compile(r"^\(")
-_OP_RE = re.compile(r"^(?:\([^)]*\)|[a-z0-9]+\[[\d,]*\](?:\{[\d,]*\})?)\s+"
-                    r"([\w\-]+)\(")
+# an array type with its optional layout; TPU layouts carry tiling
+# ("{1,0:T(8,128)(2,1)S(1)}"), CPU layouts only the dim order ("{1,0}")
+_ARRAY_TYPE_RE = re.compile(r"^[a-z0-9]+\[[\d,]*\](?:\{[^{}]*\})?")
+_KIND_RE = re.compile(r"^\s+([\w\-]+)\(")
 _SHAPE_IN_TUPLE = re.compile(r"([a-z0-9]+)\[([\d,]*)\]")
 _WHILE_RE = re.compile(r"condition=(%[\w\.\-]+),\s*body=(%[\w\.\-]+)")
 _CALLS_RE = re.compile(r"(?:calls|to_apply)=(%[\w\.\-]+)")
@@ -159,6 +161,27 @@ def _dot_contraction_size(op: _Op, table: Dict[str, str]) -> int:
     return 1
 
 
+def _type_and_kind(rhs: str) -> Optional[Tuple[str, str]]:
+    """``(type string, op kind)`` of a definition's right-hand side
+    (``"bf16[2,3]{1,0} dot(...)"`` or a tuple type ``"(f32[2], s32[])
+    tuple(...)"``, whose members' layouts may hold parentheses); None
+    when the line is not an op definition."""
+    if rhs.startswith("("):
+        depth = 0
+        for end, ch in enumerate(rhs):
+            depth += (ch == "(") - (ch == ")")
+            if depth == 0:
+                break
+        tstr, rest = rhs[:end + 1], rhs[end + 1:]
+    else:
+        tm = _ARRAY_TYPE_RE.match(rhs)
+        if tm is None:
+            return None
+        tstr, rest = tm.group(0), rhs[tm.end():]
+    km = _KIND_RE.match(rest)
+    return (tstr, km.group(1)) if km else None
+
+
 def parse_computations(hlo: str) -> Dict[str, List[_Op]]:
     comps: Dict[str, List[_Op]] = {}
     cur: Optional[str] = None
@@ -180,10 +203,10 @@ def parse_computations(hlo: str) -> Dict[str, List[_Op]]:
         if not dm:
             continue
         name, rhs = dm.group(1), dm.group(2)
-        om = _OP_RE.match(rhs)
-        kind = om.group(1) if om else rhs.split("(")[0].split()[-1]
-        tm = rhs.split(" " + kind + "(")[0] if om else ""
-        comps[cur].append(_Op(name, kind, tm, line))
+        parsed = _type_and_kind(rhs)
+        if parsed is None:
+            continue
+        comps[cur].append(_Op(name, parsed[1], parsed[0], line))
     comps["__entry__"] = comps.get(entry, [])
     comps["__entry_name__"] = entry  # type: ignore
     return comps

@@ -21,7 +21,9 @@ from repro.core.planner import (plan_summary_lines, refine_plan_from_hlo,
 from repro.models import transformer as T
 from repro.models.transformer import RunFlags
 from repro.runtime.serve import (make_prefill_step, make_decode_step,
-                                 grow_caches, resolved_serve_rules)
+                                 grow_caches, resolved_serve_rules,
+                                 serve_shardings)
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_production_mesh
 
 
@@ -108,6 +110,7 @@ def main():
                     help="--engine: write the serve dryrun artifact JSON "
                          "here (CI cross-checks it with --against-artifact)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch) if args.preset == "full" else \
         get_reduced(args.arch)
@@ -160,7 +163,11 @@ def main():
     for line in plan_summary_lines(decisions or ()):
         print(line)
 
-    params = T.init_params(jax.random.key(0), cfg, flags.param_dtype)
+    param_sh = None if mesh is None else serve_shardings(
+        cfg, mesh, args.batch, args.prompt_len + args.gen, rules,
+        flags.param_dtype)[0]
+    params = T.init_params_on_device(jax.random.key(0), cfg,
+                                     flags.param_dtype, param_sh)
     if prefill is None:
         prefill = jax.jit(make_prefill_step(cfg, flags, mesh, rules=rules,
                                             comm_plan=plan))

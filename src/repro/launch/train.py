@@ -14,6 +14,7 @@ matching device count; on this CPU container use the default --mesh none).
 from __future__ import annotations
 
 import argparse
+import functools
 import time
 
 import jax
@@ -31,6 +32,7 @@ from repro.runtime.fault import (FaultTolerantRunner, FaultError,
                                  replan_for_mesh, shrink_mesh)
 from repro.runtime.train import (make_train_step, init_state,
                                  resolved_train_rules)
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_production_mesh
 
 
@@ -67,6 +69,7 @@ def main():
                          "record (a calibration is a re-plan; see "
                          "docs/calibration.md)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch) if args.preset == "full" else \
         get_reduced(args.arch)
@@ -127,7 +130,9 @@ def main():
             jstep = compiled
     for line in plan_summary_lines(decisions or ()):
         print(line)
-    state = init_state(jax.random.key(0), cfg, flags)
+    # one compiled program: leaves are born cast (and, on a mesh, sharded)
+    state = jax.jit(functools.partial(init_state, cfg=cfg, flags=flags),
+                    out_shardings=state_sh)(jax.random.key(0))
     n_params = sum(x.size for x in jax.tree.leaves(state.params))
     print(f"arch={cfg.name} params={n_params/1e6:.1f}M "
           f"batch={args.global_batch}x{args.seq}")

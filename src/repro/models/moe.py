@@ -28,7 +28,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.core.comm import (CommMode, TransferDescriptor,
                              register_fusion_target)
 from repro.core.socket import socket_for_axis
@@ -140,7 +139,7 @@ def moe_apply(params, x, cfg, *, mode: str = "mem",
     if model_axis is None:
         M, rank, E_loc = 1, 0, E
     else:
-        M = compat.axis_size(model_axis)
+        M = jax.lax.axis_size(model_axis)
         rank = jax.lax.axis_index(model_axis)
         assert E % M == 0, f"{E} experts not divisible by model axis {M}"
         E_loc = E // M

@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core.comm import CommMode
 from repro.core.sharding import (current_comm_plan, current_mesh,
                                  logical_to_pspec)
@@ -51,7 +50,7 @@ class RunFlags:
     ffn_tp: bool = False
     # dispatch the Pallas comm kernels (multicast stream, FUSED_RING) at
     # socket sites that qualify; kernel_interpret forwards interpret-mode
-    # params on CPU (tests pass compat.interpret_params())
+    # params on CPU (tests pass interpret_params())
     use_comm_kernels: bool = False
     kernel_interpret: Any = None
 
@@ -148,8 +147,8 @@ def _moe_ffn(params, h, cfg, flags: RunFlags):
             aux = jax.lax.pmean(aux, ax)
         return y, aux
 
-    fn = compat.shard_map(body, mesh=mesh, in_specs=(param_specs, x_spec),
-                          out_specs=(x_spec, P()), check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(param_specs, x_spec),
+                       out_specs=(x_spec, P()), check_vma=False)
     y, aux = fn(params, h)
     y = mem_write(y, "moe_output", ("batch", "seq", "embed"))
     return y, aux
@@ -192,8 +191,8 @@ def _mlp_ffn_tp(params, h, flags: RunFlags):
                            compute_dtype=flags.compute_dtype)
         return y.reshape(Bl, Sl, d)
 
-    fn = compat.shard_map(body, mesh=mesh, in_specs=(param_specs, x_spec),
-                          out_specs=x_spec, check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(param_specs, x_spec),
+                       out_specs=x_spec, check_vma=False)
     y = fn({k: params[k] for k in ("w_gate", "w_up", "w_down")}, h)
     return mem_write(y, "mlp_output", ("batch", "seq", "embed"))
 
@@ -288,6 +287,17 @@ def init_params(key, cfg: ArchConfig, dtype=jnp.float32):
                                              pos=i)
                          for i, kind in enumerate(rem)}
     return params
+
+
+def init_params_on_device(key, cfg: ArchConfig, dtype=jnp.float32,
+                          shardings=None):
+    """:func:`init_params` as one compiled program.  Each leaf is drawn,
+    scaled and cast inside the program, so no float32 copy of a stacked
+    leaf outlives its cast; with ``shardings`` (a pytree of
+    ``NamedSharding`` matching the params) every leaf is born on its
+    shards instead of whole on the default device."""
+    return jax.jit(functools.partial(init_params, cfg=cfg, dtype=dtype),
+                   out_shardings=shardings)(key)
 
 
 def param_axes(cfg: ArchConfig):

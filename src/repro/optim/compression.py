@@ -25,7 +25,6 @@ from typing import Any, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.core.comm import TransferDescriptor
 from repro.core.socket import socket_for_axis
 
@@ -74,7 +73,7 @@ def compressed_psum(g: jax.Array, axis_name: str,
     s = socket_for_axis(axis_name).reduce(
         q.astype(jnp.int32), GRAD_REDUCE_COMPRESSED,
         wire_bytes=int(q.size))   # one byte per int8 element on the wire
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     mean = s.astype(jnp.float32) * scale / n
     new_res = g_ef - q.astype(jnp.float32) * scale
     return mean, new_res

@@ -198,8 +198,12 @@ class ServeEngine:
 
         # --- model state + jitted step functions (one trace each) ---
         if params is None:
-            params = T.init_params(jax.random.key(seed), cfg,
-                                   self.flags.param_dtype)
+            shardings = None if mesh is None else RS.serve_shardings(
+                cfg, mesh, n_slots, prompt_len + max_new_tokens, rules,
+                self.flags.param_dtype)[0]
+            params = T.init_params_on_device(jax.random.key(seed), cfg,
+                                             self.flags.param_dtype,
+                                             shardings)
         self.params = params
         self.trace_counts: Dict[str, int] = {"prefill": 0, "decode": 0,
                                              "admit": 0}

@@ -5,7 +5,6 @@ These are the framework-level reproductions of the paper's C1-C4."""
 _CODE = r"""
 import jax, jax.numpy as jnp, numpy as np, functools
 from jax.sharding import PartitionSpec as P
-from repro import compat
 from repro.core import p2p as P2P
 from repro.core import multicast as MC
 from repro.core import sync as SYNC
@@ -14,8 +13,8 @@ from repro.core.comm import CommMode, CommPlan, TransferDescriptor
 from repro.core.socket import StageRegistry, AcceleratorSocket
 from repro.optim.compression import compressed_psum
 
-mesh = compat.make_mesh((8,), ("s",), axis_types=(compat.AxisType.Auto,))
-smap = functools.partial(compat.shard_map, mesh=mesh, check_vma=False)
+mesh = jax.make_mesh((8,), ("s",), axis_types=(jax.sharding.AxisType.Auto,))
+smap = functools.partial(jax.shard_map, mesh=mesh, check_vma=False)
 
 # ---- C1: pull-based P2P ring shift --------------------------------------
 x = jnp.arange(8.0)[:, None] * jnp.ones((1, 4))

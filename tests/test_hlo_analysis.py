@@ -98,3 +98,23 @@ def test_multiplier_map():
     comps = parse_computations(comp.as_text())
     mult = comp_multipliers(comps)
     assert any(abs(m - 7.0) < 0.5 for m in mult.values()), mult
+
+
+def test_collective_parsing_tpu_tiled_layouts():
+    """A TPU-compiled module prints tiled layouts, whose parentheses sit
+    inside tuple types: the parser reads the op kind past them."""
+    hlo = """
+ENTRY %main (p: bf16[4,256]) -> (pred[4,256], bf16[64,256]) {
+  %p = bf16[4,256]{1,0:T(4,128)(2,1)} parameter(0)
+  %ag = bf16[16,256]{1,0:T(8,128)(2,1)S(1)} all-gather(%p), replica_groups={{0,1,2,3}}, dimensions={0}
+  %a2a = (bf16[4,256]{1,0:T(4,128)(2,1)}, bf16[4,256]{1,0:T(4,128)(2,1)}) all-to-all(%p, %p), replica_groups={{0,1}}
+  %cmp = pred[4,256]{1,0:T(4,128)(4,1)S(1)} compare(%p, %p), direction=EQ
+  ROOT %t = (pred[4,256]{1,0:T(4,128)(4,1)S(1)}, bf16[16,256]{1,0:T(8,128)(2,1)S(1)}) tuple(%cmp, %ag)
+}
+"""
+    kinds = [op.kind for op in parse_computations(hlo)["__entry__"]]
+    assert kinds == ["parameter", "all-gather", "all-to-all", "compare",
+                     "tuple"]
+    colls = parse_collectives(hlo)
+    assert colls["all-gather"].count == 1
+    assert colls["all-to-all"].count == 1

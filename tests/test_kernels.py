@@ -52,10 +52,9 @@ def test_streamed_gather_matmul_bitwise(m, dtype):
 
 _SWEEP_CODE = r"""
 import jax, jax.numpy as jnp, numpy as np
-from repro import compat
 from repro.kernels import ops, ref
 
-mesh = compat.make_mesh((8,), ("x",), axis_types=(compat.AxisType.Auto,))
+mesh = jax.make_mesh((8,), ("x",), axis_types=(jax.sharding.AxisType.Auto,))
 ip = ops.interpret_params()
 P = 8
 
@@ -97,10 +96,9 @@ def test_collective_kernel_sweep(subproc):
 _RING_EQUIV_CODE = r"""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro import compat
 from repro.kernels import ops
 
-mesh = compat.make_mesh((8,), ("x",), axis_types=(compat.AxisType.Auto,))
+mesh = jax.make_mesh((8,), ("x",), axis_types=(jax.sharding.AxisType.Auto,))
 ip = ops.interpret_params()
 Pn = 8
 
@@ -109,20 +107,20 @@ def lax_ag_mm(x, w):
         full = jax.lax.all_gather(xs, "x", axis=0, tiled=True)
         return jnp.dot(full, ws, preferred_element_type=jnp.float32
                        ).astype(jnp.promote_types(xs.dtype, ws.dtype))
-    return jax.jit(compat.shard_map(body, mesh=mesh,
-                                    in_specs=(P("x", None), P(None, None)),
-                                    out_specs=P(None, None),
-                                    check_vma=False))(x, w)
+    return jax.jit(jax.shard_map(body, mesh=mesh,
+                                 in_specs=(P("x", None), P(None, None)),
+                                 out_specs=P(None, None),
+                                 check_vma=False))(x, w)
 
 def lax_rs_mm(x, w):
     def body(xs, ws):
         part = jnp.dot(xs, ws, preferred_element_type=jnp.float32)
         return jax.lax.psum_scatter(part, "x", scatter_dimension=0,
                                     tiled=True)
-    return jax.jit(compat.shard_map(body, mesh=mesh,
-                                    in_specs=(P(None, "x"), P("x", None)),
-                                    out_specs=P("x", None),
-                                    check_vma=False))(x, w)
+    return jax.jit(jax.shard_map(body, mesh=mesh,
+                                 in_specs=(P(None, "x"), P("x", None)),
+                                 out_specs=P("x", None),
+                                 check_vma=False))(x, w)
 
 # all-gather matmul: 2 dtypes x uneven per-rank chunk counts (m = 3 rows
 # per rank is NOT a power of two; m = 8 is the friendly case)
@@ -169,13 +167,13 @@ def test_ring_kernels_match_unfused_lax(subproc):
 _FUSED_DISPATCH_CODE = r"""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro import compat
+from repro.kernels.ops import interpret_params
 from repro.core.comm import (CommMode, CommPlan, TransferDescriptor,
                              register_fusion_target)
 from repro.core import socket as SOCK
 
-mesh = compat.make_mesh((8,), ("x",), axis_types=(compat.AxisType.Auto,))
-ip = compat.interpret_params()
+mesh = jax.make_mesh((8,), ("x",), axis_types=(jax.sharding.AxisType.Auto,))
+ip = interpret_params()
 plan = CommPlan({"weights": CommMode.P2P, "grad_scatter": CommMode.P2P})
 # this subprocess never imports repro.models.layers, so the consumer-matmul
 # labels the descriptors fuse with must be registered here (the socket
@@ -195,7 +193,7 @@ def run_gather(use_kernels, p=plan):
         s = SOCK.socket_for_axis("x", p, use_kernels=use_kernels,
                                  interpret=ip)
         return s.gather_matmul(xs, ws, gdesc)
-    return jax.jit(compat.shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(P("x", None), P(None, None)),
         out_specs=P(None, None), check_vma=False))(x, w)
 
@@ -233,7 +231,7 @@ def run_rs(use_kernels):
         s = SOCK.socket_for_axis("x", plan, use_kernels=use_kernels,
                                  interpret=ip)
         return s.matmul_reduce_scatter(xs, ws, rdesc)
-    return jax.jit(compat.shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(P(None, "x"), P("x", None)),
         out_specs=P("x", None), check_vma=False))(xr, wr)
 
@@ -262,7 +260,7 @@ def run_oproj(use_kernels):
         s = SOCK.socket_for_axis("x", plan, use_kernels=use_kernels,
                                  interpret=ip)
         return o_proj_tp(cs, ws, socket=s)
-    return jax.jit(compat.shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(P(None, "x"), P("x", None)),
         out_specs=P("x", None), check_vma=False))(ctx, w_o)
 
@@ -293,14 +291,13 @@ def test_socket_fused_ring_dispatch(subproc):
 _FFN_TP_CODE = r"""
 import dataclasses
 import jax, jax.numpy as jnp, numpy as np
-from repro import compat
 from repro.configs import get_reduced
 from repro.core import socket as SOCK
 from repro.core.sharding import use_rules, DEFAULT_RULES
 from repro.models import transformer as T
 
-mesh = compat.make_mesh((2, 4), ("data", "model"),
-                        axis_types=(compat.AxisType.Auto,) * 2)
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 cfg = get_reduced("qwen3-4b")
 B, S = 4, 32
 flags0 = T.RunFlags(distributed=True, remat="none")
@@ -337,12 +334,12 @@ def test_transformer_ffn_tp_matches_gspmd(subproc):
 _STREAMED_AND_CHAIN_CODE = r"""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro import compat
+from repro.kernels.ops import interpret_params
 from repro.core.comm import CommMode, CommPlan, TransferDescriptor
 from repro.core import socket as SOCK
 
-mesh = compat.make_mesh((8,), ("s",), axis_types=(compat.AxisType.Auto,))
-ip = compat.interpret_params()
+mesh = jax.make_mesh((8,), ("s",), axis_types=(jax.sharding.AxisType.Auto,))
+ip = interpret_params()
 
 # ---- streamed-MEM weights gather: plan.streamed drives the DMA schedule ---
 from repro.models.layers import MLP_GATHER_DESC
@@ -357,7 +354,7 @@ def run_gather(use_kernels, plan):
         s = SOCK.socket_for_axis("s", plan, use_kernels=use_kernels,
                                  interpret=ip)
         return s.gather_matmul(xs, ws, MLP_GATHER_DESC)
-    return jax.jit(compat.shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(P("s", None), P(None, None)),
         out_specs=P(None, None), check_vma=False))(x, w)
 
@@ -398,7 +395,7 @@ def run_moe(use_kernels):
     def body(p, v):
         return M.moe_apply(p, v, cfg, mode="mcast", model_axis="s",
                            use_kernels=use_kernels, interpret=ip)[0]
-    return jax.jit(compat.shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(pspec, P(None, "s", None)),
         out_specs=P(None, "s", None), check_vma=False))(params, xx)
 

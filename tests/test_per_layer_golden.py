@@ -20,7 +20,6 @@ GOLDEN = os.path.join(os.path.dirname(os.path.abspath(__file__)),
 _CODE = r"""
 import dataclasses, json
 import jax
-from repro import compat
 from repro.configs import get_reduced
 from repro.configs.base import ShapeConfig
 from repro.launch.dryrun import build_comm_plan, lower_cell, make_flags
@@ -28,8 +27,8 @@ from repro.launch.hlo_analysis import transfer_specs_from_hlo
 
 cfg = dataclasses.replace(get_reduced("dbrx-132b"), name="dbrx-4l",
                           n_layers=4)
-mesh = compat.make_mesh((4, 4), ("data", "model"),
-                        axis_types=(compat.AxisType.Auto,) * 2)
+mesh = jax.make_mesh((4, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 shape = ShapeConfig("g", 128, 16, "prefill")
 flags = make_flags(cfg, shape, moe_mode="mcast")
 plan, _ = build_comm_plan("auto", cfg, shape, mesh)

@@ -465,14 +465,13 @@ def test_resolve_rules_w_fsdp_overlay():
 
 _E2E_CODE = r"""
 import jax
-from repro import compat
 from repro.configs import get_reduced
 from repro.configs.base import ShapeConfig
 from repro.core.comm import CommMode
 from repro.launch.dryrun import build_comm_plan, lower_cell, make_flags
 
-mesh = compat.make_mesh((4, 4), ("data", "model"),
-                        axis_types=(compat.AxisType.Auto,) * 2)
+mesh = jax.make_mesh((4, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 cfg = get_reduced("dbrx-132b")
 shape = ShapeConfig("t", 128, 16, "train")
 flags = make_flags(cfg, shape)

@@ -231,14 +231,14 @@ def test_degraded_reason_compat_alias():
 _LADDER_E2E_CODE = r"""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro import compat
+from repro.kernels.ops import interpret_params
 from repro.core.comm import (CommMode, CommPlan, FaultError,
                              TransferDescriptor, register_fusion_target)
 from repro.core import socket as SOCK
 import repro.kernels.ring_allgather_matmul as RK
 
-mesh = compat.make_mesh((8,), ("x",), axis_types=(compat.AxisType.Auto,))
-ip = compat.interpret_params()
+mesh = jax.make_mesh((8,), ("x",), axis_types=(jax.sharding.AxisType.Auto,))
+ip = interpret_params()
 plan = CommPlan({"weights": CommMode.P2P})
 register_fusion_target("mlp.up_proj")
 gdesc = TransferDescriptor("weights", fused_with="mlp.up_proj",
@@ -260,7 +260,7 @@ def run():
         s = SOCK.socket_for_axis("x", plan, use_kernels=True, interpret=ip,
                                  retry=pol)
         return s.gather_matmul(xs, ws, gdesc)
-    return jax.jit(compat.shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(P("x", None), P(None, None)),
         out_specs=P(None, None), check_vma=False))(x, w)
 
@@ -283,7 +283,7 @@ def run_bare():
     def body(xs, ws):
         s = SOCK.socket_for_axis("x", plan, use_kernels=True, interpret=ip)
         return s.gather_matmul(xs, ws, gdesc)
-    return jax.jit(compat.shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(P("x", None), P(None, None)),
         out_specs=P(None, None), check_vma=False))(x, w)
 try:

@@ -60,14 +60,13 @@ def test_moe_arch_trains(tmp_path):
 
 _DRYRUN_SMALL = r"""
 import jax
-from repro import compat
 from repro.configs import get_reduced
 from repro.configs.base import ShapeConfig
 from repro.launch.dryrun import lower_cell, make_flags
 from repro.launch import hlo_analysis
 
-mesh = compat.make_mesh((4, 4), ("data", "model"),
-                        axis_types=(compat.AxisType.Auto,) * 2)
+mesh = jax.make_mesh((4, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 # one family of each kind x (train, decode)
 for arch in ("smollm-135m", "dbrx-132b", "falcon-mamba-7b",
