@@ -39,7 +39,7 @@ from __future__ import annotations
 import dataclasses
 import random
 import time
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -52,6 +52,7 @@ from repro.core.socket import AcceleratorSocket, StageRegistry, issue_epoch
 from repro.models import transformer as T
 from repro.runtime import kv_blocks as KB
 from repro.runtime import serve as RS
+from repro.runtime.tracing import EngineTrace
 
 KV_PREFIX_SITE = "engine.kv_prefix"
 
@@ -70,7 +71,9 @@ class Request:
     blocks: List[int] = dataclasses.field(default_factory=list)
     generated: List[int] = dataclasses.field(default_factory=list)
     done: bool = False
-    t_visible: float = 0.0             # wall clock when arrival_step opened
+    # perf_counter seconds: the step that saw arrival_step open, the first
+    # token's read-back, the last token's read-back
+    t_visible: float = 0.0
     t_admitted: float = 0.0
     t_done: float = 0.0
 
@@ -89,7 +92,6 @@ class ServeMetrics:
     tokens_per_s: float
     p50_latency_s: float
     p99_latency_s: float
-    latencies_s: Tuple[float, ...] = ()
 
     def summary(self) -> Dict[str, float]:
         return {"n_requests": self.n_requests,
@@ -140,7 +142,19 @@ class ServeEngine:
     Tracing contract: exactly one trace per jitted function for the
     engine's lifetime (``trace_counts`` is tier-1 asserted) — admission,
     block growth, eviction and consumer remaps are all host-side table /
-    LUT updates.
+    LUT updates.  The three jitted programs are named ``jit_engine_prefill``,
+    ``jit_engine_admit`` and ``jit_engine_decode``.
+
+    With an :class:`~repro.runtime.tracing.EngineTrace` as ``trace``, each
+    step records one tree of spans: ``engine.step``; under it one
+    ``engine.admit`` per admitted request (carrying its ``rid``; under it
+    ``engine.prefill``, the prefill and admit dispatch, and
+    ``engine.first_token``, the argmax and its read-back), then
+    ``engine.upload`` (the host-to-device copies of tokens, positions and
+    tables), ``engine.decode`` (the decode dispatch), ``engine.sample``
+    (the argmax and its read-back) and ``engine.bookkeep`` (token append,
+    eviction, block growth); and the counters ``admitted``, ``active`` and
+    ``n_slots``.  Without one, nothing is recorded.
     """
 
     def __init__(self, cfg: ArchConfig, *, prompt_len: int,
@@ -150,8 +164,10 @@ class ServeEngine:
                  plan=None, params=None, seed: int = 0,
                  param_dtype=jnp.bfloat16, cache_dtype=jnp.bfloat16,
                  mesh_axes: Optional[Dict[str, int]] = None,
-                 planner: Optional[CommPlanner] = None):
+                 planner: Optional[CommPlanner] = None,
+                 trace: Optional[EngineTrace] = None):
         self.cfg = cfg
+        self.trace = trace
         self.mesh = mesh
         self.rules = rules
         self.flags = flags or T.RunFlags(param_dtype=param_dtype,
@@ -229,7 +245,32 @@ class ServeEngine:
             # so this counter measures retraces, not calls
             self.trace_counts[key] += 1
             return fn(*a)
+        # jit names the program after the function: jit_engine_<key>
+        wrapped.__name__ = wrapped.__qualname__ = f"engine_{key}"
         return wrapped
+
+    def _begin(self, name: str, rid: Optional[int] = None) -> int:
+        """The clock at a span's start; the span opens when recording."""
+        t = time.perf_counter_ns()
+        if self.trace is not None:
+            self.trace.open(name, t, rid)
+        return t
+
+    def _end(self) -> int:
+        """The clock at the innermost span's end; it closes when recording."""
+        t = time.perf_counter_ns()
+        if self.trace is not None:
+            self.trace.close(t)
+        return t
+
+    def _next(self, name: str) -> int:
+        """One clock reading that ends the innermost span and starts its
+        next sibling ``name``."""
+        t = time.perf_counter_ns()
+        if self.trace is not None:
+            self.trace.close(t)
+            self.trace.open(name, t)
+        return t
 
     def _bind_decode(self):
         # bind under the decode epoch: the step factory's downgrade
@@ -328,34 +369,38 @@ class ServeEngine:
         n0 = max(n_prefix, self.layout.blocks_needed(S))
         req.blocks = self.allocator.alloc(n0)
         req.slot = self._free_slots.pop()
+        self._begin("engine.admit", req.rid)
+        self._begin("engine.prefill")
         with issue_epoch("prefill"):
             logits, caches = self._prefill(self.params, req.prompt[None, :])
             self.pools = self._admit(
                 self.pools, caches, jnp.int32(req.slot),
                 jnp.asarray(req.blocks[:n_prefix], jnp.int32))
+        self._next("engine.first_token")
         first = int(np.asarray(jnp.argmax(logits[0, -1])))
+        req.t_admitted = self._end() * 1e-9
         self._slot_req[req.slot] = req
         self.tables[req.slot, :] = KB.NULL_BLOCK
         self.tables[req.slot, :len(req.blocks)] = req.blocks
         self._tokens[req.slot, 0] = first
         self._pos[req.slot] = S
         req.generated.append(first)
-        req.t_admitted = time.perf_counter()
+        self._end()
 
-    def _evict(self, req: Request) -> None:
+    def _evict(self, req: Request, t_done: float) -> None:
         self.allocator.free(req.blocks)
         self.tables[req.slot, :] = KB.NULL_BLOCK
         self._slot_req[req.slot] = None
         self._free_slots.append(req.slot)
         req.done = True
-        req.t_done = time.perf_counter()
+        req.t_done = t_done
         req.blocks = []
         self.completed.append(req)
 
     def step(self) -> Dict[str, int]:
         """Admit what fits, decode one token for every active slot, evict
         completions.  Returns ``{"admitted", "active", "evicted"}``."""
-        now = time.perf_counter()
+        now = self._begin("engine.step") * 1e-9
         for r in self.pending:
             if r.arrival_step <= self.step_idx and not r.t_visible:
                 r.t_visible = now
@@ -367,23 +412,28 @@ class ServeEngine:
         for req in [r for r in self._slot_req if r is not None]:
             # covers max_new_tokens == 1: the prefill token was the output
             if len(req.generated) >= req.max_new_tokens:
-                self._evict(req)
+                self._evict(req, req.t_admitted)
                 evicted += 1
         active = [r for r in self._slot_req if r is not None]
         if active:
+            self._begin("engine.upload")
+            tokens, pos, tables = (jnp.asarray(self._tokens),
+                                   jnp.asarray(self._pos),
+                                   jnp.asarray(self.tables))
+            self._next("engine.decode")
             with issue_epoch("decode"):
-                logits, self.pools = self._decode(
-                    self.params, jnp.asarray(self._tokens),
-                    jnp.asarray(self._pos), self.pools,
-                    jnp.asarray(self.tables))
+                logits, self.pools = self._decode(self.params, tokens, pos,
+                                                  self.pools, tables)
+            self._next("engine.sample")
             nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
+            now = self._next("engine.bookkeep") * 1e-9
             for req in active:
                 s = req.slot
                 req.generated.append(int(nxt[s]))
                 self._tokens[s, 0] = int(nxt[s])
                 self._pos[s] += 1
                 if len(req.generated) >= req.max_new_tokens:
-                    self._evict(req)
+                    self._evict(req, now)
                     evicted += 1
                     continue
                 need = self.layout.blocks_needed(int(self._pos[s]))
@@ -391,7 +441,12 @@ class ServeEngine:
                     new = self.allocator.alloc(need - len(req.blocks))
                     self.tables[s, len(req.blocks):need] = new
                     req.blocks.extend(new)
+            self._end()
         self.step_idx += 1
+        if self.trace is not None:
+            self.trace.count(admitted=admitted, active=len(active),
+                             n_slots=self.layout.n_slots)
+        self._end()
         return {"admitted": admitted, "active": len(active),
                 "evicted": evicted}
 
@@ -418,5 +473,4 @@ class ServeEngine:
             steps=steps, wall_s=wall,
             tokens_per_s=total / wall if wall > 0 else 0.0,
             p50_latency_s=_percentile(lats, 0.50),
-            p99_latency_s=_percentile(lats, 0.99),
-            latencies_s=tuple(lats))
+            p99_latency_s=_percentile(lats, 0.99))
