@@ -17,4 +17,7 @@ Paper mapping:
                            burst pipelining across the ICI).
   dma_double_buffer      — the IDMA/CDMA ISA pair (C5): tag-based async DMA
                            with double-buffered load/compute overlap.
+  paged_attention        — the same IDMA/CDMA pipeline on the serving
+                           path: decode attention DMAs each slot's live KV
+                           blocks through the scalar-prefetched block table.
 """

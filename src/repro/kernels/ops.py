@@ -18,6 +18,8 @@ from repro.kernels.ring_allgather_matmul import ring_allgather_matmul_local
 from repro.kernels.ring_reducescatter_matmul import ring_reducescatter_matmul_local
 from repro.kernels.multicast_stream import multicast_stream_local
 from repro.kernels.dma_double_buffer import dma_double_buffer_stream
+# decode attention over paged KV, read in place through the block table
+from repro.kernels.paged_attention import paged_decode_attention  # noqa: F401
 
 
 def interpret_params():
@@ -65,3 +67,4 @@ def dma_stream(x, scale, n_blocks=4, *, interpret=None):
     return jax.jit(functools.partial(
         dma_double_buffer_stream, n_blocks=n_blocks, interpret=interpret))(
         x, jnp.asarray([scale], jnp.float32))
+

@@ -31,3 +31,26 @@ def multicast_ref(x_src, P):
 def dma_stream_ref(x, scale):
     xf = x.astype(jnp.float32) * scale
     return (xf * jax.nn.sigmoid(xf)).astype(x.dtype)
+
+
+def paged_decode_attention_ref(q, k_pool, v_pool, tables, lengths, layer):
+    """Gather the layer's table blocks into the contiguous per-slot view,
+    then a plain float32 softmax over each slot's first ``lengths`` keys.
+    q: (n, H, hd); pools: (L, blocks, bs, K, hd); tables: (n, mb);
+    lengths: (n,).  Returns (n, H, hd) float32 (NaN for a length of 0)."""
+    n, H, hd = q.shape
+    K = k_pool.shape[3]
+
+    def view(pool):                                   # (n, mb*bs, K, hd)
+        return jnp.take(pool[layer], tables, axis=0).reshape(
+            n, -1, K, hd).astype(jnp.float32)
+
+    k, v = view(k_pool), view(v_pool)
+    qf = q.astype(jnp.float32).reshape(n, K, H // K, hd)
+    hi = jax.lax.Precision.HIGHEST
+    s = jnp.einsum("nkgd,ntkd->nkgt", qf, k, precision=hi) * hd ** -0.5
+    valid = jnp.arange(k.shape[1])[None, :] < lengths[:, None]
+    s = jnp.where(valid[:, None, None, :], s, -jnp.inf)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("nkgt,ntkd->nkgd", p, v, precision=hi).reshape(
+        n, H, hd)

@@ -1,6 +1,7 @@
 """Attention: GQA/MHA/MQA, qk-norm, RoPE + M-RoPE, blockwise prefill/train
 attention (pair-scan online softmax), banded sliding-window attention, and
-single-token decode attention over a (possibly sequence-sharded) KV cache.
+single-token decode attention over a (possibly sequence-sharded) KV cache or
+over paged block pools read in place through a block table.
 
 The pair-scan attention linearizes the (q-chunk, kv-chunk) iteration space to
 *only the blocks that contain at least one unmasked element* (lower triangle
@@ -12,6 +13,7 @@ this keeps HLO_FLOPs honest relative to MODEL_FLOPS.
 from __future__ import annotations
 
 import functools
+from typing import Any, NamedTuple
 
 import numpy as np
 
@@ -23,6 +25,7 @@ from repro.core.comm import (CommMode, TransferDescriptor,
                              register_fusion_target)
 from repro.core.sharding import logical_constraint
 from repro.core.socket import mem_write
+from repro.kernels import ops
 from repro.models.layers import _he, rmsnorm
 
 # Fused-transfer descriptor of the tensor-parallel o-projection: the
@@ -442,9 +445,7 @@ def decode_attn_apply(params, x, cfg, cache, pos_scalar, *,
     to small all-reduces — the paper's sync-region pattern: tiny control
     payloads (m, l statistics) on the fast path, bulk (cache) stays put.
     """
-    B, _, d = x.shape
-    H, K, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
-    G = H // K
+    B = x.shape[0]
     pos_b = jnp.reshape(
         jnp.broadcast_to(jnp.asarray(pos_scalar, jnp.int32), (B,)), (B, 1))
     q, k_new, v_new = _project_qkv(params, x, cfg, pos_b, compute_dtype)
@@ -462,24 +463,90 @@ def decode_attn_apply(params, x, cfg, cache, pos_scalar, *,
     k_cache = logical_constraint(k_cache, ("batch", "kv_seq", "kv_heads", "head_dim"))
     v_cache = logical_constraint(v_cache, ("batch", "kv_seq", "kv_heads", "head_dim"))
 
+    # slots written so far: min(pos + 1, Skv) — the whole ring once full
+    # (the pre-filled serve_step contract), a prefix while a paged/slot
+    # cache is still growing
+    n_valid = jnp.minimum(pos_b[:, :1] + 1, Skv)          # (B, 1)
+    ctx = _decode_context(q, k_cache, v_cache, n_valid, compute_dtype)
+    return _decode_out(params, ctx, x, cfg, compute_dtype), \
+        {"k": k_cache, "v": v_cache}
+
+
+def _decode_context(q, k_cache, v_cache, n_valid, compute_dtype):
+    """Softmax attention of one query token per row over the first
+    ``n_valid`` (B, 1) positions of a contiguous cache.  q: (B,1,K,G,hd);
+    caches: (B, Skv, K, hd).  Returns the f32 context (B,1,K,G,hd)."""
+    hd, Skv = q.shape[-1], k_cache.shape[1]
     s = jnp.einsum("bqkgh,bskh->bkgqs", q.astype(compute_dtype),
                    k_cache.astype(compute_dtype),
                    preferred_element_type=jnp.float32) * (hd ** -0.5)
-    # slots written so far: min(pos + 1, Skv) — the whole ring once full
-    # (the pre-filled serve_step contract), a prefix while a paged/slot
-    # cache is still growing.  s: (B, K, G, 1, Skv).
-    n_valid = jnp.minimum(pos_b[:, :1] + 1, Skv)          # (B, 1)
     valid = (jax.lax.broadcasted_iota(jnp.int32, (1, Skv), 1)
              < n_valid)[:, None, None, None, :]           # (B,1,1,1,Skv)
-    s = jnp.where(valid, s, -jnp.inf)
+    s = jnp.where(valid, s, -jnp.inf)                     # (B,K,G,1,Skv)
     m = jnp.max(s, axis=-1, keepdims=True)
     p = jnp.exp(s - m)
     l = jnp.sum(p, axis=-1, keepdims=True)
-    ctx = jnp.einsum("bkgqs,bskh->bqkgh", (p / l).astype(compute_dtype),
-                     v_cache.astype(compute_dtype),
-                     preferred_element_type=jnp.float32)
+    return jnp.einsum("bkgqs,bskh->bqkgh", (p / l).astype(compute_dtype),
+                      v_cache.astype(compute_dtype),
+                      preferred_element_type=jnp.float32)
+
+
+def _decode_out(params, ctx, x, cfg, compute_dtype):
+    """The o-projection of a (B,1,K,G,hd) decode context."""
+    d = x.shape[-1]
+    K, G, hd = ctx.shape[2:]
     y = jnp.einsum("bqkgh,kghd->bqd", ctx.astype(compute_dtype),
                    params["w_o"].astype(compute_dtype).reshape(K, G, hd, d),
                    preferred_element_type=jnp.float32).astype(x.dtype)
-    y = logical_constraint(y, ("batch", None, "embed"))
-    return y, {"k": k_cache, "v": v_cache}
+    return logical_constraint(y, ("batch", None, "embed"))
+
+
+class PagedKV(NamedTuple):
+    """One block kind's paged KV history, read and written in place.
+
+    ``k``/``v``: the whole stacked block pools (layers, blocks, block_size,
+    K, hd); ``tables``: the (B, max_blocks) int32 block table; ``layer``:
+    the scan's layer index (None outside the layer scan)."""
+    k: Any
+    v: Any
+    tables: Any
+    layer: Any = None
+
+
+def _write_rows(pool, layer, tables, pos, rows):
+    """Land each slot's new (K, hd) row at ``(layer, tables[b, pos // bs],
+    pos % bs)`` of the stacked pool; nothing else of the pool moves.  An
+    inactive slot's all-null table row lands it on the null block."""
+    bs = pool.shape[2]
+    blk = jnp.take_along_axis(tables, (pos // bs)[:, None], axis=1)[:, 0]
+    return pool.at[layer, blk, pos % bs].set(rows.astype(pool.dtype))
+
+
+def paged_decode_attn_apply(params, x, cfg, cache: PagedKV, pos, *,
+                            compute_dtype=jnp.bfloat16):
+    """One-token decode over a paged, unwindowed KV history.  x: (B,1,d);
+    pos: (B,) int32.  The new token's K and V are written into the pools
+    first, so each slot attends over its first ``pos + 1`` positions,
+    read through the block table: by the Pallas kernel on a TPU, and off
+    it by the same softmax as :func:`decode_attn_apply` over a ``jnp.take``
+    of the layer's table blocks.  Returns (y, cache with the new pools)."""
+    B = x.shape[0]
+    H, K, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
+    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
+    q, k_new, v_new = _project_qkv(params, x, cfg, pos[:, None],
+                                   compute_dtype)
+    k_pool = _write_rows(cache.k, cache.layer, cache.tables, pos, k_new[:, 0])
+    v_pool = _write_rows(cache.v, cache.layer, cache.tables, pos, v_new[:, 0])
+    lengths = pos + 1
+    if jax.default_backend() == "tpu":
+        ctx = ops.paged_decode_attention(
+            q.reshape(B, H, hd).astype(compute_dtype), k_pool, v_pool,
+            cache.tables, lengths, cache.layer).reshape(q.shape)
+    else:
+        def view(pool):                                # (B, mb*bs, K, hd)
+            return jnp.take(pool[cache.layer], cache.tables, axis=0).reshape(
+                B, -1, K, hd)
+        ctx = _decode_context(q, view(k_pool), view(v_pool), lengths[:, None],
+                              compute_dtype)
+    return _decode_out(params, ctx, x, cfg, compute_dtype), \
+        cache._replace(k=k_pool, v=v_pool)

@@ -206,7 +206,12 @@ def block_apply(params, x, cfg: ArchConfig, kind: str, flags: RunFlags,
 
     window = cfg.local_window if kind == "swa" else cfg.sliding_window
     if kind in ("attn", "swa"):
-        if decode:
+        if decode and isinstance(cache, A.PagedKV):
+            assert not window, "a windowed history is read as a ring"
+            y, new_cache = A.paged_decode_attn_apply(
+                params["mixer"], h, cfg, cache, pos,
+                compute_dtype=flags.compute_dtype)
+        elif decode:
             y, new_cache = A.decode_attn_apply(
                 params["mixer"], h, cfg, cache, pos,
                 compute_dtype=flags.compute_dtype, window=window)
@@ -389,12 +394,24 @@ def _apply_stack(params, x, cfg, flags, pos, caches, decode, collect_cache):
                 return x, (ncs, aux_g)
             x, (g_new, g_aux) = jax.lax.scan(scan_body, x, params["groups"])
         else:
-            def scan_body(x, inp):
-                gp, gc = inp
+            # paged histories ride the carry, whole, and each layer reads
+            # and writes them in place at its index; the rest is scanned
+            paged = {n: c for n, c in gcaches.items()
+                     if isinstance(c, A.PagedKV)}
+            scanned = {n: c for n, c in gcaches.items() if n not in paged}
+
+            def scan_body(carry, inp):
+                x, pools = carry
+                gp, gc, layer = inp
+                gc = dict(gc, **{n: c._replace(layer=layer)
+                                 for n, c in pools.items()})
                 x, ncs, aux_g = body(x, gp, gc)
-                return x, (ncs, aux_g)
-            x, (g_new, g_aux) = jax.lax.scan(scan_body, x,
-                                             (params["groups"], gcaches))
+                pools = {n: ncs.pop(n)._replace(layer=None) for n in pools}
+                return (x, pools), (ncs, aux_g)
+            (x, paged), (g_new, g_aux) = jax.lax.scan(
+                scan_body, (x, paged),
+                (params["groups"], scanned, jnp.arange(n_groups)))
+            g_new.update(paged)
         aux_total = aux_total + jnp.sum(g_aux)
         if keep_cache:
             new_caches["groups"] = g_new
@@ -449,7 +466,11 @@ def prefill(params, tokens, cfg: ArchConfig, flags: RunFlags):
 def decode_step(params, token, pos_scalar, caches, cfg: ArchConfig,
                 flags: RunFlags):
     """token (B,1) int32, pos_scalar scalar int32 — or a (B,) int32 vector
-    of per-row positions (continuous batching) -> (logits (B,1,V), caches)."""
+    of per-row positions (continuous batching) -> (logits (B,1,V), caches).
+
+    A block's cache is either its contiguous history or an
+    :class:`attention.PagedKV` over the stacked block pools, which the
+    step reads and writes in place (returned as an updated ``PagedKV``)."""
     B = token.shape[0]
     x = L.embed_tokens(params["embed"], token, flags.compute_dtype)
     x, new_caches, _ = _apply_stack(params, x, cfg, flags, pos_scalar, caches,

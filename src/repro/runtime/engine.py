@@ -153,8 +153,11 @@ class ServeEngine:
     ``engine.upload`` (the host-to-device copies of tokens, positions and
     tables), ``engine.decode`` (the decode dispatch), ``engine.sample``
     (the argmax and its read-back) and ``engine.bookkeep`` (token append,
-    eviction, block growth); and the counters ``admitted``, ``active`` and
-    ``n_slots``.  Without one, nothing is recorded.
+    eviction, block growth); and the counters ``admitted``, ``active``,
+    ``n_slots``, ``kv_blocks_read`` (KV blocks the decode's attention
+    reads, summed over active slots) and ``kv_blocks_span`` (``n_slots *
+    max_blocks``, what a gather of every table row touches).  Without
+    one, nothing is recorded.
     """
 
     def __init__(self, cfg: ArchConfig, *, prompt_len: int,
@@ -179,6 +182,7 @@ class ServeEngine:
                                       block_size=block_size,
                                       dtype=self.flags.cache_dtype)
         self.allocator = KB.BlockAllocator(1 + self.layout.capacity_blocks)
+        self._in_place = RS.decodes_in_place(self.layout, mesh)
         self.pools = KB.make_pools(self.layout)
         self.tables = KB.null_table(self.layout)
 
@@ -276,11 +280,14 @@ class ServeEngine:
         # bind under the decode epoch: the step factory's downgrade
         # records (the MoE dispatch chain's decode_no_seq_dim demotion)
         # key as "moe.dispatch@decode" in the artifact's issue summary
+        # the pools are donated: the step writes its rows into them in
+        # place and self.pools is rebound from its result
         with issue_epoch("decode"):
             self._decode = jax.jit(self._counted(
                 "decode", RS.make_paged_decode_step(self.cfg, self.flags,
                                                     self.layout, self.mesh,
-                                                    self.rules, self.plan)))
+                                                    self.rules, self.plan)),
+                donate_argnums=(3,))
 
     def _admit_fn(self, pools, prefix_caches, slot, block_ids):
         """Traced once: multicast one request's prefill caches through the
@@ -390,12 +397,23 @@ class ServeEngine:
     def _evict(self, req: Request, t_done: float) -> None:
         self.allocator.free(req.blocks)
         self.tables[req.slot, :] = KB.NULL_BLOCK
+        # an empty slot decodes at position 0: one block, the null one
+        self._pos[req.slot] = 0
         self._slot_req[req.slot] = None
         self._free_slots.append(req.slot)
         req.done = True
         req.t_done = t_done
         req.blocks = []
         self.completed.append(req)
+
+    def _blocks_read(self, active: Sequence[Request]) -> int:
+        """KV blocks the decode's attention reads for the active slots:
+        ``ceil((pos + 1) / block_size)`` each in place, the whole table
+        row each on the gather path."""
+        if not self._in_place:
+            return len(active) * self.layout.max_blocks
+        return sum(int(self._pos[r.slot]) // self.layout.block_size + 1
+                   for r in active)
 
     def step(self) -> Dict[str, int]:
         """Admit what fits, decode one token for every active slot, evict
@@ -415,6 +433,9 @@ class ServeEngine:
                 self._evict(req, req.t_admitted)
                 evicted += 1
         active = [r for r in self._slot_req if r is not None]
+        blocks_read = 0
+        if active and self.trace is not None:
+            blocks_read = self._blocks_read(active)
         if active:
             self._begin("engine.upload")
             tokens, pos, tables = (jnp.asarray(self._tokens),
@@ -445,7 +466,10 @@ class ServeEngine:
         self.step_idx += 1
         if self.trace is not None:
             self.trace.count(admitted=admitted, active=len(active),
-                             n_slots=self.layout.n_slots)
+                             n_slots=self.layout.n_slots,
+                             kv_blocks_read=blocks_read,
+                             kv_blocks_span=self.layout.n_slots *
+                             self.layout.max_blocks)
         self._end()
         return {"admitted": admitted, "active": len(active),
                 "evicted": evicted}

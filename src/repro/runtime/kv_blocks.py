@@ -16,8 +16,13 @@ fixed-size blocks instead:
   active request's logical block j to its physical block, and is passed
   to the decode step as a device array — growing a request is a host
   table write, never a retrace;
-* :func:`gather_caches` materializes the contiguous per-slot view the
-  unchanged model decode consumes (``jnp.take`` over the block axis);
+* where every leaf is paged, unwindowed attention history
+  (:attr:`PagedLayout.in_place`), :func:`paged_caches` hands the decode
+  step the whole pools as :class:`~repro.models.attention.PagedKV`: it
+  writes each slot's new row in place and reads the live blocks through
+  the table, with no contiguous view;
+* otherwise :func:`gather_caches` materializes the contiguous per-slot
+  view the model decode consumes (``jnp.take`` over the block axis) and
   :func:`scatter_caches` writes back only the single block containing
   each slot's write position.
 
@@ -45,6 +50,7 @@ import numpy as np
 
 from repro.configs.base import ArchConfig
 from repro.models import transformer as T
+from repro.models.attention import PagedKV
 
 
 # ----------------------------------------------------------- block allocator ----
@@ -112,6 +118,7 @@ class LeafSpec:
     contig_shape: Tuple[int, ...]
     paged: bool
     skv: Optional[int] = None      # kv length of the contiguous view
+    window: int = 0                # attention window a paged leaf masks
 
     @property
     def batch_ax(self) -> int:
@@ -152,6 +159,14 @@ class PagedLayout:
         default pool provisioning), excluding the null block."""
         return self.n_slots * self.max_blocks
 
+    @property
+    def in_place(self) -> bool:
+        """Every leaf is paged, unwindowed attention history inside the
+        layer scan: decode can read and write the pools in place."""
+        leaves = jax.tree.leaves(self.specs, is_leaf=_spec_is_leaf)
+        return set(self.specs) == {"groups"} and all(
+            sp.paged and not sp.window for sp in leaves)
+
     def blocks_needed(self, pos: int) -> int:
         """Blocks a request must own before writing position ``pos``."""
         return min(pos // self.block_size + 1, self.max_blocks)
@@ -174,7 +189,7 @@ def _leaf_specs_for_kind(cfg: ArchConfig, kind: str, n_slots: int,
         names = ("batch", "kv_seq", "kv_heads", "head_dim")
         spec = LeafSpec(names=names, dtype=dtype,
                         contig_shape=(n_slots, skv, K, hd),
-                        paged=not ring, skv=skv)
+                        paged=not ring, skv=skv, window=window or 0)
         return {"k": spec, "v": spec}
     if kind == "mamba":
         di = cfg.ssm.expand * cfg.d_model
@@ -296,6 +311,21 @@ def scatter_caches(layout: PagedLayout, pools, new_caches, tables, pos):
     return jax.tree.map(
         lambda sp, pool, nc: _scatter_leaf(layout, sp, pool, nc, tables, pos),
         layout.specs, pools, new_caches, is_leaf=_spec_is_leaf)
+
+
+def paged_caches(layout: PagedLayout, pools, tables):
+    """The decode step's caches over :attr:`PagedLayout.in_place` pools:
+    one :class:`PagedKV` per block of the layer scan, holding the whole
+    stacked pools and the block table."""
+    assert layout.in_place
+    return {"groups": {n: PagedKV(leaf["k"], leaf["v"], tables)
+                       for n, leaf in pools["groups"].items()}}
+
+
+def pools_of(caches):
+    """Inverse of :func:`paged_caches`: the pools a decode step returned."""
+    return {"groups": {n: {"k": c.k, "v": c.v}
+                       for n, c in caches["groups"].items()}}
 
 
 def _write_prefix_leaf(layout: PagedLayout, spec: LeafSpec, pool,

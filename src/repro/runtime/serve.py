@@ -182,16 +182,23 @@ def make_batched_decode_step(cfg: ArchConfig, flags: T.RunFlags, mesh=None,
 def make_paged_decode_step(cfg: ArchConfig, flags: T.RunFlags,
                            layout: "KB.PagedLayout", mesh=None, rules=None,
                            comm_plan=None):
-    """Block-table decode for the serving engine: gather the paged pools
-    into the contiguous per-slot view, run one batched decode step, and
-    scatter back only the block containing each slot's write position.
+    """Block-table decode for the serving engine.
 
     ``step(params, tokens, pos, pools, tables)``: tokens ``(n_slots, 1)``,
     pos ``(n_slots,)``, ``tables`` the ``(n_slots, max_blocks)`` int32
-    block table.  Growing a request's cache is a host-side table update —
-    the step never retraces."""
+    block table; returns ``(logits, pools)``.  Growing a request's cache
+    is a host-side table update — the step never retraces.
+
+    Where :func:`decodes_in_place` holds, each layer writes its new K and
+    V rows into the pools and reads each slot's live blocks through the
+    table (donate ``pools`` and nothing else of them is copied).
+    Otherwise — rings, recurrent state, a windowed history, or a mesh,
+    under which the kernel would not partition — the step gathers the
+    contiguous per-slot view, runs the contiguous batched decode, and
+    scatters back the block holding each slot's write position."""
     rules = rules or SERVE_RULES
     flags, comm_plan = _decode_downgrades(cfg, flags, comm_plan)
+    in_place = decodes_in_place(layout, mesh)
 
     def step(params, tokens, pos, pools, tables):
         with use_rules(rules, mesh, comm_plan=comm_plan):
@@ -202,6 +209,11 @@ def make_paged_decode_step(cfg: ArchConfig, flags: T.RunFlags,
                                                   rules),
                     impl="xla_all_gather", site="decode.weights_gather",
                     reason="w_fsdp gate not cleared: gather rides memory")
+            if in_place:
+                logits, caches = T.decode_step(
+                    params, tokens, pos, KB.paged_caches(layout, pools,
+                                                         tables), cfg, flags)
+                return logits, KB.pools_of(caches)
             caches = KB.gather_caches(layout, pools, tables)
             logits, new_caches = T.decode_step(params, tokens, pos, caches,
                                                cfg, flags)
@@ -209,3 +221,9 @@ def make_paged_decode_step(cfg: ArchConfig, flags: T.RunFlags,
             return logits, pools
 
     return step
+
+
+def decodes_in_place(layout: "KB.PagedLayout", mesh=None) -> bool:
+    """Whether :func:`make_paged_decode_step` reads the pools in place:
+    every leaf is paged, unwindowed attention history, and no mesh."""
+    return mesh is None and layout.in_place

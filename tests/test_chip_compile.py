@@ -9,6 +9,7 @@ import: only one process at a time may load the TPU library, and under
 pytest-xdist every worker imports this file.
 """
 
+import dataclasses
 import functools
 import importlib.util
 import os
@@ -24,7 +25,9 @@ from repro.kernels import ops
 from repro.kernels.streamed_gather import streamed_gather_matmul
 from repro.launch.mesh import make_device_mesh
 from repro.models import transformer as T
-from repro.runtime.serve import make_prefill_step, serve_shardings
+from repro.runtime import kv_blocks as KB
+from repro.runtime.serve import (make_paged_decode_step, make_prefill_step,
+                                 serve_shardings)
 from repro.runtime.train import SERVE_RULES
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -173,13 +176,54 @@ def _kernel_programs(smoke, one_chip, four_chips):
             streamed_gather_matmul, single((m, k)), single((k, n))),
         "dma_double_buffer_stream": (
             lambda x: ops.dma_stream(x, 1.3), single((m, k))),
+        # qwen3-4b's layer: 8 slots, 32 heads over 8 kv heads, blocks of 16
+        "paged_decode_attention": (
+            ops.paged_decode_attention, single((8, 32, 128)),
+            single((36, 513, 16, 8, 128)), single((36, 513, 16, 8, 128)),
+            single((8, 64), jnp.int32), single((8,), jnp.int32),
+            single((), jnp.int32)),
     }
 
 
 @pytest.mark.parametrize("name", [
     "allgather_matmul", "reducescatter_matmul", "multicast",
-    "streamed_gather_matmul", "dma_double_buffer_stream"])
+    "streamed_gather_matmul", "dma_double_buffer_stream",
+    "paged_decode_attention"])
 def test_kernel_compiles_for_the_chip(smoke, one_chip, four_chips, name):
     fn, *args = _kernel_programs(smoke, one_chip, four_chips)[name]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_engine_decode_reads_the_pools_in_place(smoke, one_chip,
+                                                monkeypatch):
+    """The engine's decode program at qwen3-4b.decode-saturated's shapes
+    (8 slots, blocks of 16, s_max 1024; the cell ties the embeddings),
+    with the paged kernel the chip takes: it fits one chip, its donated
+    pools are written in place, and nothing of the size of the cache is
+    copied or kept beside them."""
+    cfg = dataclasses.replace(get_config(smoke.SERVE_ARCH),
+                              tie_embeddings=True)
+    params = jax.eval_shape(
+        lambda: T.init_params(jax.random.key(0), cfg, jnp.bfloat16))
+    params = _with_sharding(params, jax.tree.map(lambda _: one_chip, params))
+    flags = T.RunFlags(param_dtype=jnp.bfloat16, remat="none")
+    layout = KB.paged_layout(cfg, n_slots=8, prompt_len=256,
+                             max_new_tokens=768, block_size=16)
+    pools = _with_sharding(KB.pool_specs(layout), jax.tree.map(
+        lambda _: one_chip, KB.pool_specs(layout)))
+    ints = lambda shape: jax.ShapeDtypeStruct(shape, jnp.int32,
+                                              sharding=one_chip)
+    # the step picks the kernel by the backend it runs on; this process
+    # runs on the CPU and compiles for the described chip
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    step = make_paged_decode_step(cfg, flags, layout)
+    compiled = jax.jit(step, donate_argnums=(3,)).lower(
+        params, ints((8, 1)), ints((8,)), pools, ints((8, 64))).compile()
+    ma = compiled.memory_analysis()
+    pool_bytes = sum(s.size * s.dtype.itemsize
+                     for s in jax.tree.leaves(pools))
+    assert "paged_decode_attention" in compiled.as_text()
+    assert ma.alias_size_in_bytes >= pool_bytes
+    assert ma.temp_size_in_bytes < 0.01 * pool_bytes
+    assert _peak(compiled) < HBM_BYTES

@@ -15,6 +15,7 @@ from repro.configs import get_reduced
 from repro.core import socket as SOCK
 from repro.core.comm import CommMode, CommPlan
 from repro.models import transformer as T
+from repro.runtime import kv_blocks as KB
 from repro.runtime import serve as RS
 from repro.runtime.engine import ServeEngine, ServeMetrics, poisson_trace
 
@@ -111,6 +112,54 @@ def test_poisson_trace_is_deterministic():
                       seed=9)
     assert [r.arrival_step for r in a] == [r.arrival_step for r in b]
     assert all((x.prompt == y.prompt).all() for x, y in zip(a, b))
+
+
+def test_decode_donates_the_pools_and_traces_once():
+    eng = _engine()
+    prompts = _prompts(eng.cfg, 3, seed=51)
+    for i, arr in enumerate((0, 0, 1)):
+        eng.submit(prompts[i], arrival_step=arr, rid=i)
+    handed = []
+    decode = eng._decode
+
+    def spy(params, tokens, pos, pools, tables):
+        handed.append(pools)
+        return decode(params, tokens, pos, pools, tables)
+
+    eng._decode = spy
+    while eng.pending or eng.n_active:
+        eng.step()
+        # the step wrote into the pools it was handed, which are gone;
+        # the engine holds the ones it returned
+        assert all(leaf.is_deleted() for leaf in jax.tree.leaves(handed[-1]))
+        assert not any(leaf.is_deleted()
+                       for leaf in jax.tree.leaves(eng.pools))
+    assert len(handed) > 3 and eng.trace_counts["decode"] == 1
+    assert len(eng.completed) == 3
+
+
+def test_engine_with_a_mesh_binds_the_gather_scatter_step(monkeypatch):
+    """The kernel does not partition under the sequence-sharded serve
+    rules, so an engine built with a mesh keeps the gather/scatter step;
+    without one it reads in place.  Both serve the same tokens."""
+    gathers = []
+    gather = KB.gather_caches
+    monkeypatch.setattr(KB, "gather_caches",
+                        lambda *a: gathers.append(1) or gather(*a))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    served = {}
+    for name, m in (("mesh", mesh), ("none", None)):
+        eng = _engine(mesh=m)
+        for i, p in enumerate(_prompts(eng.cfg, 2, seed=61)):
+            eng.submit(p, rid=i)
+        while eng.pending or eng.n_active:
+            eng.step()
+        served[name] = {r.rid: r.generated for r in eng.completed}
+        assert eng._in_place is (m is None)
+        # traced once each: one gather with the mesh, none without
+        assert len(gathers) == 1
+    assert served["mesh"] == served["none"]
 
 
 # ------------------------------------------ tokens vs contiguous decode ----

@@ -78,6 +78,40 @@ def test_one_step_span_per_step_and_counters_equal_step(served):
                                if st["active"] else [])
 
 
+def test_kv_block_counters_equal_what_the_tables_imply():
+    """``kv_blocks_read`` is ``ceil((pos + 1) / block_size)`` summed over
+    the slots the decode step ran for (those with a live table row), and
+    ``kv_blocks_span`` the whole table, on every step."""
+    tr = EngineTrace()
+    eng = _engine(tr)
+    inputs = []
+    decode = eng._decode
+
+    def spy(params, tokens, pos, pools, tables):
+        inputs.append((np.array(pos), np.array(tables)))
+        return decode(params, tokens, pos, pools, tables)
+
+    eng._decode = spy
+    infos = _serve(eng)
+    steps = tr.export()["steps"]
+    decoded = iter(inputs)
+    bs, mb = eng.layout.block_size, eng.layout.max_blocks
+    for st, info in zip(steps, infos):
+        assert st["kv_blocks_span"] == 2 * mb
+        if not info["active"]:
+            assert st["kv_blocks_read"] == 0
+            continue
+        pos, tables = next(decoded)
+        live = tables[:, 0] != 0
+        assert live.sum() == info["active"]
+        want = int(np.sum((pos[live] + 1 + bs - 1) // bs))
+        # the table holds every block the step reads, and no fewer
+        assert want <= int(np.sum(tables[live] != 0))
+        assert st["kv_blocks_read"] == want
+    assert next(decoded, None) is None
+    assert sum(st["kv_blocks_read"] for st in steps) > 0
+
+
 def test_one_admit_span_per_admitted_request(served):
     eng, ex, infos = served
     admits = [s for s in ex["spans"] if s.name == "engine.admit"]

@@ -48,6 +48,43 @@ def test_streamed_gather_matmul_bitwise(m, dtype):
                                   np.asarray(expect, np.float32))
 
 
+@pytest.mark.parametrize("bs,blocks_per_chunk", [(2, 3), (8, None),
+                                                  (16, 1)])
+@pytest.mark.parametrize("G", [1, 4])
+def test_paged_decode_attention_matches_gathered_view(bs, blocks_per_chunk,
+                                                      G):
+    """The paged decode kernel, reading each slot's blocks in place through
+    a permuted block table, against the plain float32 softmax over the
+    gathered contiguous view.  Lengths: one token, exactly one block, an
+    empty slot (zeros, and the pipeline passes over it), a ragged tail,
+    and the full table; entries past each length stay on the null block.
+    bf16 pools and queries, f32 accumulation."""
+    K, hd, L, layer, mb = 2, 128, 3, 1, 4
+    lengths = np.asarray([1, bs, 0, 2 * bs + 1, mb * bs], np.int32)
+    n = len(lengths)
+    NB = 1 + n * mb
+    rng = np.random.default_rng(bs * 10 + G)
+    kp, vp = (jnp.asarray(rng.normal(size=(L, NB, bs, K, hd)), jnp.bfloat16)
+              for _ in range(2))
+    q = jnp.asarray(rng.normal(size=(n, K * G, hd)), jnp.bfloat16)
+    phys = rng.permutation(np.arange(1, NB))
+    tables = np.zeros((n, mb), np.int32)                 # null block: 0
+    for s, ln in enumerate(lengths):
+        live = -(-ln // bs)
+        tables[s, :live] = phys[s * mb:s * mb + live]
+    args = (q, kp, vp, jnp.asarray(tables), jnp.asarray(lengths),
+            jnp.int32(layer))
+    got = ops.paged_decode_attention(*args, blocks_per_chunk=blocks_per_chunk,
+                                     interpret=ops.interpret_params())
+    want = ref.paged_decode_attention_ref(*args)
+    assert got.shape == q.shape and got.dtype == jnp.bfloat16
+    got = np.asarray(got, np.float32)
+    live = lengths > 0
+    np.testing.assert_allclose(got[live], np.asarray(want)[live],
+                               rtol=2e-2, atol=2e-2)
+    np.testing.assert_array_equal(got[~live], 0.0)
+
+
 # ------------------------------------------------ multi-device (subproc) ----
 
 _SWEEP_CODE = r"""

@@ -86,11 +86,16 @@ def _paged_state(cfg, caches, prompt_len, gen, bs, perm):
     return lay, pools, tables
 
 
-def _assert_paged_equals_contiguous(arch, prompt_len, gen, bs, perm):
+def _assert_paged_equals_contiguous(arch, prompt_len, gen, bs, perm,
+                                    donate=False):
     cfg, params, logits0, caches = _setup(arch, prompt_len)
     lay, pools, tables = _paged_state(cfg, caches, prompt_len, gen, bs, perm)
     paged_step = RS.make_paged_decode_step(cfg, FLAGS, lay)
     ref_step = RS.make_batched_decode_step(cfg, FLAGS)
+    if donate:
+        # the engine's binding: one compiled program, pools donated
+        paged_step = jax.jit(paged_step, donate_argnums=(3,))
+        ref_step = jax.jit(ref_step)
     ref_caches = RS.grow_caches(cfg, caches, prompt_len, gen)
 
     tok = jnp.argmax(logits0[:, -1], axis=-1).astype(jnp.int32)[:, None]
@@ -129,3 +134,15 @@ def test_recurrent_state_case_mamba():
     # gather/scatter (including the pool-dtype coercion)
     _assert_paged_equals_contiguous("falcon-mamba-7b", S, 4, 8,
                                     list(reversed(range(_PERM_WIDTH))))
+
+
+def test_in_place_step_crosses_a_block_boundary_with_donated_pools():
+    # the prompt fills exactly three blocks of 4: the first decode opens
+    # a fresh block and the fifth opens another, each written in place
+    # into pools the compiled step consumes
+    cfg = get_reduced("qwen3-4b")
+    assert RS.decodes_in_place(KB.paged_layout(
+        cfg, n_slots=N_SLOTS, prompt_len=S, max_new_tokens=8, block_size=4))
+    _assert_paged_equals_contiguous("qwen3-4b", S, 8, 4,
+                                    list(reversed(range(_PERM_WIDTH))),
+                                    donate=True)
