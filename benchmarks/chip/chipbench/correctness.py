@@ -58,27 +58,35 @@ def served_gaps(ref, w, conf, tokens, next_tokens, mask, control=False):
     return jax.lax.map(one, (tokens, next_tokens, mask))
 
 
-def prefill_readings(ref, w, conf, tokens, first, kv, control=False):
-    """Per prompt: the gap of the first token (the program's, or with
-    ``control`` the float8 control's) and the relative error of the last
-    layer's k and v (the program's cache, or the control's) against the
-    reference, the larger of the two.
+def prefill_errors(ref, w, conf, tokens, logits, kv, control=False):
+    """Per prompt, what the program's prefill made (or with ``control``
+    the float8 control's) against the reference, token by token:
 
-    tokens (n, S); first (n,); kv: (k, v) each (n, S, K, hd).  Returns
-    (gaps (n,), kv_rel (n,))."""
+    * ``kv_err`` (n, S): each token's relative error of the last layer's
+      k and v, the larger of the two;
+    * ``logit_err`` (n,): the relative error of the last position's
+      logits;
+    * ``margin`` (L, n, S): the reference's router margin of each token in
+      each layer (``references/dbrx.forward``), by which the caller leaves
+      out tokens whose experts a rounding may change.
+
+    tokens (n, S); logits (n, V); kv: (k, v) each (n, S, K, hd)."""
+    def rel(a, b, axes):
+        a, b = a.astype(F32), b.astype(F32)
+        return jnp.sqrt(jnp.sum((a - b) ** 2, axes) / jnp.sum(b * b, axes))
+
     def one(args):
-        tok, fst, k, v = args
-        h, (rk, rv) = ref.forward(w, tok[None], conf)
+        tok, lg_p, k, v = args
+        h, (rk, rv), margin = ref.forward(w, tok[None], conf, margins=True)
         lg = ref.logits(w, h[0, -1], conf)
         if control:
             hc, (k, v) = ref.forward(w, tok[None], conf, ein=fp8_ein)
             k, v = k[0], v[0]
-            fst = jnp.argmax(ref.logits(w, hc[0, -1], conf, ein=fp8_ein))
-        gap = jnp.max(lg) - lg[fst]
+            lg_p = ref.logits(w, hc[0, -1], conf, ein=fp8_ein)
+        kv_err = jnp.maximum(rel(k, rk[0], (-2, -1)), rel(v, rv[0], (-2, -1)))
+        return {"kv_err": kv_err, "logit_err": rel(lg_p, lg, None),
+                "margin": margin[:, 0]}
 
-        def rel(a, b):
-            a, b = a.astype(F32), b.astype(F32)
-            return jnp.linalg.norm(a - b) / jnp.linalg.norm(b)
-        return gap, jnp.maximum(rel(k, rk[0]), rel(v, rv[0]))
-
-    return jax.lax.map(one, (tokens, first, kv[0], kv[1]))
+    out = jax.lax.map(one, (tokens, logits, kv[0], kv[1]))
+    out["margin"] = jnp.moveaxis(out["margin"], 0, 1)
+    return out

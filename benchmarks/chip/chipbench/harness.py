@@ -13,8 +13,8 @@ Layout under ``benchmarks/chip/`` (each item found by the name that
 * ``checks/<cell>.json``: the limits of the comparison that decides
   ``correct`` in that cell.
 
-``pending.json`` holds, in ``BENCHMARK.json``'s shape, cells that are
-built and tested on the CPU but not yet measured on the chip.
+A cell's ``chips`` sets how the program is laid out: over more than one
+chip, on a (data=1, model=chips) mesh of the first ``chips`` devices.
 """
 
 from __future__ import annotations
@@ -32,7 +32,6 @@ from typing import Any, Dict, List, Optional
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(os.path.dirname(HERE))
 BENCHMARK_JSON = os.path.join(ROOT, "BENCHMARK.json")
-PENDING_JSON = os.path.join(HERE, "pending.json")
 CACHE_DIR = os.path.join(HERE, ".jax_cache")
 
 

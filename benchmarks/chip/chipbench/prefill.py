@@ -87,18 +87,21 @@ class Prefill:
         log(f"moe_dispatch verdict {self.moe_mode}; compiled prefill "
             f"collectives {dict(sorted(colls.items()))}")
         self.first = jax.jit(lambda lg: jnp.argmax(lg[:, -1], -1))
+        # what the check reads of a kept step: the logits (those of each
+        # prompt's last position) and the last layer's k and v
+        self.keep = jax.jit(lambda lg, c: (
+            lg[:, -1], (c["groups"]["b0"]["k"][-1],
+                        c["groups"]["b0"]["v"][-1])))
         logits, caches = self.step(self.params, self.batches[0])
         np.asarray(self.first(logits))
-        self.kv_of = jax.jit(lambda c: (c["groups"]["b0"]["k"][-1],
-                                        c["groups"]["b0"]["v"][-1]))
-        jax.block_until_ready(self.kv_of(caches))
+        jax.block_until_ready(self.keep(logits, caches))
         del logits, caches
 
     def drive(self, seed: int, seconds: float, t_start: float,
               tracer=None) -> PrefillRun:
         """Steps back to back from ``t_start`` for ``seconds``; the steps
         named by ``check.steps`` (drawn from the seed among the first
-        ``check.steps_from``) keep their first tokens and caches."""
+        ``check.steps_from``) keep what the check reads."""
         chk = self.cell.checks
         rng = np.random.default_rng(seed ^ 0x5EED)
         keep = set(int(i) for i in rng.choice(
@@ -120,7 +123,7 @@ class Prefill:
                 t1 = time.perf_counter()
             steps.append((t0, t1))
             if i in keep:
-                kept[i] = (b, first, caches)
+                kept[i] = (b, self.keep(logits, caches))
             del logits, caches
             i += 1
         if tracer:
@@ -135,20 +138,19 @@ class Prefill:
                           sample={"kept": kept, "missing": len(missing)})
 
     def sample_for_check(self, run: PrefillRun) -> dict:
-        """Host copies of what the check reads; frees the kept caches."""
-        toks, firsts, ks, vs = [], [], [], []
+        """Host copies of what the check reads; frees the kept arrays."""
+        toks, lgs, ks, vs = [], [], [], []
         for i in sorted(run.sample["kept"]):
-            b, first, caches = run.sample["kept"][i]
-            k, v = self.kv_of(caches)
+            b, (lg, (k, v)) = run.sample["kept"][i]
             toks.append(np.asarray(self.batches[b]))
-            firsts.append(first)
+            lgs.append(np.asarray(lg))
             ks.append(np.asarray(k))
             vs.append(np.asarray(v))
         run.sample["kept"].clear()
         if not toks:
             return {"n": 0}
         return {"n": len(toks) * self.B, "tokens": np.concatenate(toks),
-                "first": np.concatenate(firsts),
+                "logits": np.concatenate(lgs),
                 "k": np.concatenate(ks), "v": np.concatenate(vs),
                 "missing": run.sample["missing"]}
 
@@ -157,39 +159,55 @@ class Prefill:
         gc.collect()
 
 
-def check(cell, seed: int, sample: dict, control: bool = False) -> Dict:
+def errors(cell, seed: int, sample: dict, control: bool = False) -> Dict:
     """The reference (weights drawn again from the seed, experts over the
-    same chips) on every checked prompt."""
-    if not sample.get("n") or sample.get("missing"):
-        return {"first_token_gap": None, "kv_rel_err": None}
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    same chips) on every checked prompt: ``correctness.prefill_errors``
+    as host arrays."""
     ref = cell.reference()
     specs = ref.weight_specs(cell.conf)
-    mesh = Mesh(np.asarray(jax.devices()[:cell.chips]), ("e",))
-    # experts over the chips as the program holds them, the vocabulary
-    # too (a replicated float32 copy of the head would not fit), the rest
-    # whole on each chip
-    split = {"layers/w_gate": P(None, "e"), "layers/w_up": P(None, "e"),
-             "layers/w_down": P(None, "e"), "embed": P("e"),
-             "unembed": P("e")}
-    sh = {}
-    for name in specs:
-        spec = split.get(name, P())
-        parts = name.split("/")
-        (sh.setdefault(parts[0], {}) if len(parts) == 2 else sh)[
-            parts[-1]] = NamedSharding(mesh, spec)
+    sh = weights.reference_shardings(specs, cell.chips)
     w = weights.generator(specs, jnp.bfloat16, shardings=sh)(seed)
-    fn = jax.jit(lambda w, t, f, k, v: correctness.prefill_readings(
-        ref, w, cell.conf, t, f, (k, v), control=control))
+    fn = jax.jit(lambda w, t, lg, k, v: correctness.prefill_errors(
+        ref, w, cell.conf, t, lg, (k, v), control=control))
     with jax.default_matmul_precision("highest"):
-        gaps, rel = fn(w, sample["tokens"], sample["first"], sample["k"],
-                       sample["v"])
-    gaps, rel = np.asarray(gaps), np.asarray(rel)
+        per = fn(w, sample["tokens"], sample["logits"], sample["k"],
+                 sample["v"])
+    per = {k: np.asarray(v) for k, v in per.items()}
     del w
-    log(f"check: first-token gaps {np.round(gaps, 5).tolist()}, "
-        f"kv rel err {np.round(rel, 6).tolist()}")
-    return {"first_token_gap": float(gaps.max()),
-            "kv_rel_err": float(rel.max())}
+    return per
+
+
+def readings(per: Dict, tie_margin: Dict[str, float], tag: str = "",
+             quiet: bool = False) -> Dict:
+    """The numbers compared, over every checked prompt: the largest
+    relative error of the last layer's k, v of one token, and of one
+    prompt's logits (at its last position), among the tokens that the
+    reference routes clear of a tie: router margin ``tie_margin[name]`` or
+    more in every layer that the number depends on (those before the last
+    for k and v, all for the logits).  A token nearer a tie may take other
+    experts after any rounding, and then differs by its own size; the more
+    tokens a number spans, the wider the margin it needs (PERF.md).
+    ``None`` where no token is left."""
+    kv_ok = per["margin"][:-1].min(0) >= tie_margin["kv_err_max"]
+    lg_ok = per["margin"][:, :, -1].min(0) >= tie_margin["logit_err_max"]
+    kv = np.where(kv_ok, per["kv_err"], -np.inf)
+    lg = np.where(lg_ok, per["logit_err"], -np.inf)
+    if not quiet:
+        log(f"check{tag}: clear of a tie (margins {tie_margin}): "
+            f"{kv_ok.mean():.3f} of k, v, {lg_ok.mean():.3f} of logits; "
+            f"per prompt: worst kv_err {np.round(kv.max(1), 5).tolist()}, "
+            f"logit_err {np.round(lg, 5).tolist()}")
+    return {"kv_err_max": float(kv.max()) if kv_ok.any() else None,
+            "logit_err_max": float(lg.max()) if lg_ok.any() else None}
+
+
+def check(cell, seed: int, sample: dict, control: bool = False) -> Dict:
+    """The readings that the cell's ``checks`` compare."""
+    if not sample.get("n") or sample.get("missing"):
+        return dict.fromkeys(cell.checks["limits"])
+    per = errors(cell, seed, sample, control)
+    return readings(per, cell.checks["tie_margin"],
+                    " (control)" if control else "")
 
 
 def metrics_e2e(run: PrefillRun) -> Dict[str, float]:

@@ -46,22 +46,39 @@ class ServeRun:
 
 def build(cell, seed: int):
     """Weights from the seed and the engine over them, warmed up on the
-    cell's shapes (one admission and one decode step)."""
+    cell's shapes (one admission and one decode step).  On more than one
+    chip the engine runs over a (data=1, model=chips) mesh of the first
+    ``cell.chips`` devices with the program's serving rules, and each
+    weight is drawn straight onto its shards; on one chip it gets no
+    mesh."""
     from repro.runtime.engine import ServeEngine
     ref, adapter = cell.reference(), cell.adapter()
     mix = cell.mix
     cfg = adapter.arch_config(cell.conf)
     specs = ref.weight_specs(cell.conf)
+    P, out_max, slots = int(mix["prompt_tokens"]), _max_out(mix), \
+        int(mix["slots"])
+    mesh = rules = mesh_axes = shardings = None
+    if cell.chips > 1:
+        from repro.launch.mesh import make_device_mesh
+        from repro.runtime.serve import serve_shardings
+        from repro.runtime.train import SERVE_RULES
+        mesh = make_device_mesh(model=cell.chips,
+                                devices=jax.devices()[:cell.chips])
+        rules, mesh_axes = SERVE_RULES, dict(mesh.shape)
+        shardings = serve_shardings(cfg, mesh, slots, P + out_max, rules,
+                                    jnp.bfloat16)[0]
     make = weights.generator(
         specs, jnp.bfloat16,
-        adapt=lambda canon: adapter.program_params(canon, cell.conf))
+        adapt=lambda canon: adapter.program_params(canon, cell.conf),
+        shardings=shardings)
     params = make(seed)
     jax.block_until_ready(params)
-    out_max = _max_out(mix)
-    eng = ServeEngine(cfg, prompt_len=int(mix["prompt_tokens"]),
-                      max_new_tokens=out_max, n_slots=int(mix["slots"]),
-                      block_size=int(mix["block_size"]), params=params)
-    warm = np.zeros(int(mix["prompt_tokens"]), np.int32)
+    eng = ServeEngine(cfg, prompt_len=P, max_new_tokens=out_max,
+                      n_slots=slots, block_size=int(mix["block_size"]),
+                      params=params, mesh=mesh, rules=rules,
+                      mesh_axes=mesh_axes)
+    warm = np.zeros(P, np.int32)
     eng.submit(warm, 2, rid=-1)
     while eng.pending or eng.n_active:
         eng.step()
@@ -202,13 +219,15 @@ def sample_finished(cell, eng, seed: int) -> dict:
 
 def check(cell, seed: int, sample: dict, control: bool = False) -> Dict:
     """The readings compared against the cell's limits, by the plain
-    reference (weights drawn again from the seed).  Call with the
-    program's state freed."""
+    reference (weights drawn again from the seed, over the same chips).
+    Call with the program's state freed."""
     if not sample.get("n"):
         return {"served_gap": None}
     gc.collect()
     ref = cell.reference()
-    w = weights.generator(ref.weight_specs(cell.conf), jnp.bfloat16)(seed)
+    specs = ref.weight_specs(cell.conf)
+    sh = weights.reference_shardings(specs, cell.chips)
+    w = weights.generator(specs, jnp.bfloat16, shardings=sh)(seed)
     fn = jax.jit(lambda w, t, x, m: correctness.served_gaps(
         ref, w, cell.conf, t, x, m, control=control))
     with jax.default_matmul_precision("highest"):

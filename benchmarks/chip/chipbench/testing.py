@@ -14,12 +14,9 @@ TINY_QWEN3 = dict(num_hidden_layers=2, hidden_size=64,
 
 
 def tiny_cell(name: str, **mix_overrides) -> harness.Cell:
-    """``name``'s cell (from ``BENCHMARK.json`` or ``pending.json``) with a
-    small model and a short mix."""
-    bench = harness.load_json(harness.BENCHMARK_JSON)
-    in_bench = any(w["name"] == name for w in bench["workloads"])
-    cell = harness.load_cell(name, harness.BENCHMARK_JSON if in_bench
-                             else harness.PENDING_JSON)
+    """``name``'s cell from ``BENCHMARK.json`` with a small model and a
+    short mix."""
+    cell = harness.load_cell(name)
     conf = copy.deepcopy(cell.conf)
     mix = dict(cell.mix)
     if cell.family == "qwen3":
@@ -41,5 +38,5 @@ def tiny_cell(name: str, **mix_overrides) -> harness.Cell:
     if "requests" in checks:
         checks["requests"] = 3
     else:
-        checks.update(steps=2, steps_from=2)
+        checks.update(steps=4, steps_from=4)
     return dataclasses.replace(cell, conf=conf, mix=mix, checks=checks)

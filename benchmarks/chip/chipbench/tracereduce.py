@@ -28,13 +28,21 @@ COLLECTIVES = ("all-to-all", "all-reduce", "all-gather", "reduce-scatter",
                "collective-permute", "collective-broadcast", "ragged-all-to-all")
 _COLL_RE = re.compile(r"^(%s)(-start|-done)?(\.\d+)?$" % "|".join(
     re.escape(c) for c in COLLECTIVES))
+# the opcode in an instruction's text: the first lowercase word before a
+# "(" after the " = " (layouts in the type hold only upper-case tiles)
+_OPCODE_RE = re.compile(r"(?<![\w.-])([a-z][a-z0-9-]*)\(")
 
 
 @dataclasses.dataclass
 class DeviceOps:
-    """One device's operations: ``ops`` as ``(name, start_ns, end_ns)``."""
+    """One device's operations: ``ops`` as ``(name, start_ns, end_ns)``;
+    ``kinds`` gives, by name, the ``collective_kind`` of each collective
+    op, read from its opcode (JAX names a collective after its primitive:
+    an all-reduce may be ``psum.7``, an all-to-all ``all_to_all.15``)."""
     name: str
     ops: List[Tuple[str, float, float]]
+    kinds: Dict[str, Tuple[str, str]] = dataclasses.field(
+        default_factory=dict)
 
 
 @dataclasses.dataclass
@@ -62,13 +70,18 @@ def load(path: str, host_names: Optional[Iterable[str]] = None) -> Trace:
     devices, host = [], []
     for plane in pd.planes:
         if DEVICE_PLANE.match(plane.name):
-            ops = []
+            ops, kinds = [], {}
             for line in plane.lines:
-                if line.name == OPS_LINE:
-                    ops.extend((op_name(e.name), float(e.start_ns),
-                                float(e.end_ns)) for e in line.events)
+                if line.name != OPS_LINE:
+                    continue
+                for e in line.events:
+                    name = op_name(e.name)
+                    ops.append((name, float(e.start_ns), float(e.end_ns)))
+                    kind = collective_kind(op_code(e.name))
+                    if kind is not None:
+                        kinds[name] = kind
             ops.sort(key=lambda o: o[1])
-            devices.append(DeviceOps(plane.name, ops))
+            devices.append(DeviceOps(plane.name, ops, kinds))
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 for e in line.events:
@@ -84,6 +97,14 @@ def op_name(event_name: str) -> str:
     """The HLO instruction's name: a TPU trace names each op event by the
     instruction's whole text (``%fusion.3 = bf16[...] fusion(...)``)."""
     return event_name.split(" = ", 1)[0].lstrip("%")
+
+
+def op_code(event_name: str) -> str:
+    """The HLO opcode in an op event's text (``all-reduce`` for ``%psum.7
+    = bf16[512,1024]{1,0:T(8,128)(2,1)} all-reduce(...)``), or ``""``."""
+    parts = event_name.split(" = ", 1)
+    m = _OPCODE_RE.search(parts[1]) if len(parts) == 2 else None
+    return m.group(1) if m else ""
 
 
 def union(intervals: Iterable[Interval]) -> List[Interval]:
@@ -134,15 +155,18 @@ def collective_kind(op_name: str) -> Optional[Tuple[str, str]]:
     return (m.group(1), m.group(2) or "") if m else None
 
 
-def split_ops(ops: Sequence[Tuple[str, float, float]]
+def split_ops(ops: Sequence[Tuple[str, float, float]],
+              kinds: Optional[Dict[str, Tuple[str, str]]] = None
               ) -> Tuple[List[Interval], List[Interval]]:
-    """(collective intervals, other intervals) of one device.  An async
+    """(collective intervals, other intervals) of one device.  An op is a
+    collective by ``kinds`` (its opcode) or else by its name.  An async
     collective spans from its ``-start`` to the matching ``-done`` (the
     earliest open start of the same kind)."""
     coll, other = [], []
     open_starts: Dict[str, List[float]] = {}
+    kinds = kinds or {}
     for name, s, e in ops:
-        ck = collective_kind(name)
+        ck = kinds.get(name) or collective_kind(name)
         if ck is None:
             other.append((s, e))
             continue
@@ -156,6 +180,16 @@ def split_ops(ops: Sequence[Tuple[str, float, float]]
     for starts in open_starts.values():   # a start whose done fell outside
         coll.extend((s, s) for s in starts)
     return coll, other
+
+
+def leaves(intervals: Iterable[Interval]) -> List[Interval]:
+    """The intervals that hold no other.  A device runs one op at a time
+    but for nesting: a ``while`` (or ``call``, ``conditional``) op spans the
+    ops of its body, so an op that holds the op after it is a container."""
+    ivs = sorted(intervals)
+    return [(s, e) for i, (s, e) in enumerate(ivs)
+            if not (i + 1 < len(ivs) and ivs[i + 1][0] < e
+                    and ivs[i + 1][1] <= e and ivs[i + 1] != (s, e))]
 
 
 def window_of(trace: Trace, annotation: Optional[str] = None) -> Interval:
@@ -190,9 +224,11 @@ class Reduction:
 
 
 def reduce(trace: Trace, window: Interval, n_top: int = 10) -> Reduction:
-    """Busy time (union of device-op intervals), collective-only time,
-    the ops that took most time, and the device's idle time named by the
-    host span it falls in (a span over the whole window names nothing)."""
+    """Busy time (union of device-op intervals), collective-only time
+    (collective intervals not covered by another op that holds no other:
+    a loop around a collective does not hide it), the ops that took most
+    time, and the device's idle time named by the host span it falls in
+    (a span over the whole window names nothing)."""
     lo, hi = window
     if not trace.devices:
         raise ValueError("the trace holds no device plane")
@@ -202,12 +238,15 @@ def reduce(trace: Trace, window: Interval, n_top: int = 10) -> Reduction:
     by_name: Dict[str, float] = {}
     idle_by: Dict[str, float] = {}
     for dev in trace.devices:
-        coll, other = split_ops(dev.ops)
+        coll, other = split_ops(dev.ops, dev.kinds)
         coll_u = union(clip(coll, lo, hi))
-        other_u = union(clip(other, lo, hi))
-        busy_u = union(coll_u + other_u)
+        busy_u = union(coll_u + union(clip(other, lo, hi)))
         busy.append(total(busy_u))
-        exposed.append(total(subtract(coll_u, other_u)))
+        # containers are judged over every op: a loop whose body holds
+        # only collectives hides them as much as one that holds others
+        inner = set(leaves((s, e) for _, s, e in dev.ops))
+        exposed.append(total(subtract(coll_u, union(clip(
+            [o for o in other if o in inner], lo, hi)))))
         for name, s, e in dev.ops:
             d = min(e, hi) - max(s, lo)
             if d > 0:

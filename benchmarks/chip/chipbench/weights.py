@@ -79,3 +79,28 @@ def generator(specs: Dict, dtype=jnp.bfloat16, *,
 
     fn = jax.jit(make, out_shardings=shardings)
     return lambda seed: fn(jnp.asarray(seed_words(seed)))
+
+
+def reference_shardings(specs: Dict, chips: int):
+    """Where the reference's canonical weights live on the first ``chips``
+    chips: the experts (the axis after the layer index of a four-axis
+    ``layers/`` leaf) and the vocabulary (rows of ``embed`` and
+    ``unembed``) over the chips, as the program holds them (a replicated
+    float32 copy of the head would not fit), the rest whole on each chip.
+    ``None`` on one chip: the weights stay where JAX puts them."""
+    if chips == 1:
+        return None
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    mesh = Mesh(np.asarray(jax.devices()[:chips]), ("e",))
+    out: Dict = {}
+    for name, (shape, _, _) in specs.items():
+        if name in ("embed", "unembed"):
+            spec = P("e")
+        elif name.startswith("layers/") and len(shape) == 4:
+            spec = P(None, "e")
+        else:
+            spec = P()
+        parts = name.split("/")
+        (out.setdefault(parts[0], {}) if len(parts) == 2 else out)[
+            parts[-1]] = NamedSharding(mesh, spec)
+    return out

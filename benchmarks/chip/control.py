@@ -2,7 +2,9 @@
 program's on every seed of ``--seeds`` and the float8 control's on every
 seed of ``--control-seeds`` (each after the same short window at the
 cell's own load and sizes).  Prints one JSON line per seed; not part of a
-benchmark run.
+benchmark run.  For the prefill cell each line also holds the readings at
+each tie margin of ``LADDER``, the rule that sets the cell's
+``tie_margin``.
 
     python3 benchmarks/chip/control.py --workload <cell> --seconds <s> \
         --seeds 1,2,3 --control-seeds 1,2,3
@@ -23,6 +25,8 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(HERE)),
                                 "src"))
 
 from chipbench import harness, prefill, serving  # noqa: E402
+
+LADDER = (0.0, 0.01, 0.02, 0.03, 0.05, 0.075, 0.1, 0.125, 0.15)
 
 
 def readings(cell, seed: int, seconds: float, control: bool):
@@ -45,11 +49,21 @@ def readings(cell, seed: int, seconds: float, control: bool):
     pf.close()
     del pf
     gc.collect()
-    out = prefill.check(cell, seed, sample)
-    if control:
-        out.update({k + ".control": v for k, v in prefill.check(
-            cell, seed, sample, control=True).items()})
-    return out
+    tie = cell.checks["tie_margin"]
+    out, ladder = {}, {}
+    for tag, ctl in [("", False)] + [(".control", True)] * control:
+        per = prefill.errors(cell, seed, sample, ctl)
+        out.update({k + tag: v for k, v in prefill.readings(
+            per, tie, tag).items()})
+        for t in LADDER:
+            kv_ok = per["margin"][:-1].min(0) >= t
+            lg_ok = per["margin"][:, :, -1].min(0) >= t
+            ladder.setdefault(str(t), {}).update({
+                "kv_share": float(kv_ok.mean()),
+                "logit_share": float(lg_ok.mean())})
+            ladder[str(t)].update({k + tag: v for k, v in prefill.readings(
+                per, dict.fromkeys(tie, t), tag, quiet=True).items()})
+    return dict(out, ladder=ladder)
 
 
 def main(argv=None) -> int:

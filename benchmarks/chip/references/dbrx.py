@@ -4,15 +4,15 @@
 The attention, norms, rotary embeddings and output head are those of the
 Qwen3 reference (``references/qwen3.py``) without qk-norm.  Each layer's
 feed-forward is DBRX's MoE: a float32 router, softmax over the experts, the
-top ``moe_top_k`` kept and renormalised to sum to one
-(``moe_normalize_expert_weights: 1``), and each chosen expert's SwiGLU
+top ``moe_top_k`` kept and divided by their sum (``transformers``' default
+``moe_normalize_expert_weights`` of 1), and each chosen expert's SwiGLU
 output weighted by its gate.  Written plainly: every expert runs over every
 token and the gates, zero for experts not chosen, combine them.  Nothing is
 dropped (DBRX is dropless).
 
 Departures from the published model, the same as the configuration run
 (``configs/dbrx-132b-ep4.json``): RMSNorm in place of DBRX's LayerNorm
-without bias, and no ``clip_qkv`` (DBRX clips q, k and v to [-8, 8]); the
+without bias, and no ``clip_qkv`` (DBRX clamps q, k and v to [-8, 8]); the
 program under test expresses neither.  Weights are random from a seed.
 """
 
@@ -70,12 +70,18 @@ def weight_specs(conf):
 
 
 def moe(top_k):
+    """The MoE feed-forward: (output, router margin of each token).  The
+    margin is the gap between the ``top_k``-th largest router logit and
+    the next: a rounding of the router's input by about that much changes
+    the token's experts."""
     def ffn(lw, h, ein):
         B, S, d = h.shape
         x = h.reshape(B * S, d)
-        probs = jax.nn.softmax(
-            jnp.einsum("nd,de->ne", x, lw["router"],
-                       precision=jax.lax.Precision.HIGHEST), axis=-1)
+        logits = jnp.einsum("nd,de->ne", x, lw["router"],
+                            precision=jax.lax.Precision.HIGHEST)
+        top = jax.lax.top_k(logits, top_k + 1)[0]
+        margin = (top[:, top_k - 1] - top[:, top_k]).reshape(B, S)
+        probs = jax.nn.softmax(logits, axis=-1)
         gates, idx = jax.lax.top_k(probs, top_k)
         gates = gates / jnp.sum(gates, -1, keepdims=True)
         E = probs.shape[-1]
@@ -86,13 +92,34 @@ def moe(top_k):
         y = ein("enf,efd->end", jax.nn.silu(g) * u, lw["w_down"])
         y = jnp.einsum("ne,end->nd", combine, y,
                        precision=jax.lax.Precision.HIGHEST)
-        return y.reshape(B, S, d)
+        return y.reshape(B, S, d), margin
     return ffn
 
 
-def forward(w, tokens, conf, ein=highest):
+def forward(w, tokens, conf, ein=highest, margins=False):
+    """tokens (B, S) int32 -> (final hidden states (B, S, d), float32; (k,
+    v) of the last layer), as ``qwen3.forward``; with ``margins`` also
+    each layer's router margin of each token, (L, B, S)."""
     c = _conf(conf)
-    return qwen3.forward(w, tokens, c, ein=ein, ffn=moe(c["top_k"]))
+    eps = c["rms_norm_eps"]
+    B, S = tokens.shape
+    pos = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
+    x = jnp.take(w["embed"], tokens, axis=0).astype(F32)
+    ffn = moe(c["top_k"])
+
+    def layer(carry, lw):
+        x, _ = carry
+        a, kv = qwen3.attention(lw, qwen3.rmsnorm(x, lw["ln1"], eps), pos,
+                                c, ein)
+        x = x + a
+        y, margin = ffn(lw, qwen3.rmsnorm(x, lw["ln2"], eps), ein)
+        return (x + y, kv), margin
+
+    m = dims(conf)
+    kv0 = (jnp.zeros((B, S, m["K"], m["hd"]), F32),) * 2
+    (x, kv_last), mg = jax.lax.scan(layer, (x, kv0), w["layers"])
+    h = qwen3.rmsnorm(x, w["final_norm"].astype(F32), eps)
+    return (h, kv_last, mg) if margins else (h, kv_last)
 
 
 def logits(w, h, conf, ein=highest):

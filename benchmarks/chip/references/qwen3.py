@@ -114,7 +114,7 @@ def mlp(lw, h, ein):
     return ein("bsf,fd->bsd", jax.nn.silu(g) * u, lw["w_down"])
 
 
-def forward(w, tokens, conf, ein=highest, ffn=mlp):
+def forward(w, tokens, conf, ein=highest):
     """tokens (B, S) int32 -> (final hidden states (B, S, d) after the final
     norm, float32; (k, v) of the last layer).  Layers run one at a time
     (a scan); weights are cast to float32 where ``ein`` reads them."""
@@ -127,7 +127,7 @@ def forward(w, tokens, conf, ein=highest, ffn=mlp):
         x, _ = carry
         a, kv = attention(lw, rmsnorm(x, lw["ln1"], eps), pos, conf, ein)
         x = x + a
-        x = x + ffn(lw, rmsnorm(x, lw["ln2"], eps), ein)
+        x = x + mlp(lw, rmsnorm(x, lw["ln2"], eps), ein)
         return (x, kv), None
 
     m = dims(conf)

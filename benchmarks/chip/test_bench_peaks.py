@@ -26,7 +26,8 @@ def test_unknown_device_raises():
 
 
 @pytest.mark.parametrize("cell", ["qwen3-4b.decode-saturated",
-                                  "qwen3-4b.prefill-burst"])
+                                  "qwen3-4b.prefill-burst",
+                                  "dbrx-132b.prefill-ep4"])
 def test_run_exits_nonzero_without_a_tpu(cell):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     proc = subprocess.run(
@@ -51,11 +52,9 @@ class _FakeTPU:
     device_kind = "TPU v5 lite"
 
 
-@pytest.mark.parametrize("path", [harness.BENCHMARK_JSON,
-                                  harness.PENDING_JSON])
-def test_every_cell_finds_its_files(path):
-    for w in harness.load_json(path)["workloads"]:
-        cell = harness.load_cell(w["name"], path)
+def test_every_cell_finds_its_files():
+    for w in harness.load_json(harness.BENCHMARK_JSON)["workloads"]:
+        cell = harness.load_cell(w["name"])
         assert cell.reference() and cell.adapter()
         for m in cell.per_layer:
             assert callable(harness.metric_reader(m["name"]))

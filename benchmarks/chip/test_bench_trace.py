@@ -38,6 +38,30 @@ def test_async_collective_spans_start_to_done():
     assert TR.collective_kind("fusion.all-reduce") is None
 
 
+def test_collective_by_opcode_and_inside_a_loop():
+    assert TR.op_code("%psum.7 = bf16[512,1024]{1,0:T(8,128)(2,1)} "
+                      "all-reduce(bf16[512,1024]{1,0:T(8,128)(2,1)S(1)} "
+                      "%x), channel_id=1") == "all-reduce"
+    assert TR.op_code("%fusion = (bf16[8]{0:T(256)}, u32[]{:S(2)}) "
+                      "fusion(bf16[16]{0} %a), kind=kLoop") == "fusion"
+    assert TR.op_code("barrier-cores") == ""
+    # a loop op spans its body; the all-to-all inside it runs alone
+    dev = TR.DeviceOps("/device:TPU:0", [
+        ("while.1", 0, 100), ("fusion.2", 1, 40), ("all_to_all.3", 40, 60),
+        ("fusion.4", 60, 99)], {"all_to_all.3": ("all-to-all", "")})
+    red = TR.reduce(TR.Trace([dev], []), (0, 100))
+    assert red.collective_exposed_s == pytest.approx(20e-9)
+    assert red.busy_s == pytest.approx(100e-9)
+    assert TR.leaves([(0, 100), (1, 40), (40, 60), (60, 99), (99, 99)]) \
+        == [(1, 40), (40, 60), (60, 99), (99, 99)]
+    # a loop whose body is one collective: still a container
+    dev = TR.DeviceOps("/device:TPU:0", [
+        ("while.1", 0, 100), ("all_to_all.2", 1, 99), ("fusion.3", 100, 150)],
+        {"all_to_all.2": ("all-to-all", "")})
+    red = TR.reduce(TR.Trace([dev], []), (0, 150))
+    assert red.collective_exposed_s == pytest.approx(98e-9)
+
+
 def test_reduce_on_a_made_up_trace():
     dev = TR.DeviceOps("/device:TPU:0", [
         ("fusion.1", 0, 100), ("all-reduce.2", 100, 150),
@@ -54,8 +78,8 @@ def test_reduce_on_a_made_up_trace():
     assert red.top_ops[0] == ("fusion.1", pytest.approx(200e-9))
 
 
-def test_recorded_trace():
-    chips = 1
+@pytest.mark.parametrize("chips", [1, 4])
+def test_recorded_trace(chips):
     path = os.path.join(FIXTURES, f"trace_{chips}chip.xplane.pb")
     tr = TR.load(path, host_names=ANNOTATIONS)
     assert len(tr.devices) == chips
@@ -67,4 +91,9 @@ def test_recorded_trace():
     names = dict(red.idle_by_host)
     assert names.get("traffic.wait", 0) > 0
     assert names.get("engine.step.decode", 0) > 0
-    assert red.collective_exposed_s == 0      # one chip: no collective
+    if chips == 1:
+        assert red.collective_exposed_s == 0      # no collective
+    else:
+        # the psum across the chips runs alone on each after its matmul
+        assert red.collective_exposed_s > 0
+        assert 0 < red.collective_exposed_share < 1
