@@ -21,6 +21,7 @@ from repro.configs import (
     recurrentgemma_9b,
     falcon_mamba_7b,
     qwen2_vl_72b,
+    jamba2_mini,
 )
 
 _MODULES = {
@@ -34,6 +35,7 @@ _MODULES = {
     "recurrentgemma-9b": recurrentgemma_9b,
     "falcon-mamba-7b": falcon_mamba_7b,
     "qwen2-vl-72b": qwen2_vl_72b,
+    "jamba2-mini": jamba2_mini,
 }
 
 ARCH_NAMES = tuple(_MODULES)

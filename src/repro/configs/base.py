@@ -16,7 +16,9 @@ RG-LRU pattern.  Supported kinds:
 * ``"rglru"``   — RG-LRU recurrent block (RecurrentGemma)
 * ``"mamba"``   — Mamba-1 selective-scan block (attention free)
 
-The feed-forward part of a block is dense or MoE depending on ``moe``.
+Every block is a mixer of its kind followed by a feed-forward, dense or MoE
+by its position in ``moe_pattern`` (none where ``d_ff`` and ``dense_ff``
+are 0, as in Falcon-Mamba).
 """
 
 from __future__ import annotations
@@ -31,6 +33,9 @@ class MoEConfig:
     top_k: int
     # Token capacity per expert = capacity_factor * tokens / n_experts.
     capacity_factor: float = 1.25
+    # divide the top-k gates by their sum (DBRX, Mixtral); Jamba keeps the
+    # raw softmax probabilities of the chosen experts
+    normalize_gates: bool = True
 
 
 @dataclasses.dataclass(frozen=True)
@@ -39,6 +44,8 @@ class SSMConfig:
     expand: int = 2           # d_inner = expand * d_model
     conv_dim: int = 4         # depthwise causal conv width
     dt_rank: int = 0          # 0 -> ceil(d_model / 16)
+    # RMSNorms with learned gains on dt, B and C after x_proj (Jamba)
+    inner_norms: bool = False
 
 
 @dataclasses.dataclass(frozen=True)
@@ -74,6 +81,7 @@ class ArchConfig:
     mrope: bool = False       # M-RoPE (sections over head_dim; Qwen2-VL)
     mrope_sections: Tuple[int, ...] = (16, 24, 24)  # t, h, w half-dim splits
     non_parametric_ln: bool = False   # OLMo-style LN without learned scale
+    rope: bool = True         # rotary positions on q and k (Jamba: none)
     rope_theta: float = 10000.0
     tie_embeddings: bool = False
     # Modality frontend stub: "none" | "audio_tokens" | "vision_patches".
@@ -125,13 +133,12 @@ class ArchConfig:
                           + di * self.ssm.state_dim  # A
                           + di                  # D
                           + di * d)             # out_proj
+                if self.ssm.inner_norms:
+                    total += dtr + 2 * self.ssm.state_dim
             total += 2 * d  # norms
         # FFN params (kind- and position-aware)
-        kinds = self.block_kinds()
         plen = len(self.pattern)
-        for i, kind in enumerate(kinds):
-            if kind == "mamba":
-                continue
+        for i in range(self.n_layers):
             is_moe = self.moe is not None and (
                 self.moe_pattern is None or self.moe_pattern[i % plen])
             if is_moe:
@@ -150,9 +157,8 @@ class ArchConfig:
         d = self.d_model
         total = self.param_count()
         plen = len(self.pattern)
-        n_moe = sum(1 for i, k in enumerate(self.block_kinds())
-                    if k in ("attn", "swa", "rglru") and (
-                        self.moe_pattern is None or self.moe_pattern[i % plen]))
+        n_moe = sum(1 for i in range(self.n_layers)
+                    if self.moe_pattern is None or self.moe_pattern[i % plen])
         all_experts = n_moe * self.moe.n_experts * 3 * d * self.d_ff
         active = n_moe * self.moe.top_k * 3 * d * self.d_ff
         return total - all_experts + active

@@ -116,7 +116,8 @@ def attn_axes(cfg):
 
 
 def _project_qkv(params, x, cfg, pos, compute_dtype):
-    """x (B,S,d) -> q (B,S,K,G,hd), k/v (B,S,K,hd), rope applied."""
+    """x (B,S,d) -> q (B,S,K,G,hd), k/v (B,S,K,hd), rope applied unless
+    the arch has no positional encoding (``cfg.rope`` off: Jamba)."""
     H, K, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
     G = H // K
     xc = x.astype(compute_dtype)
@@ -126,9 +127,10 @@ def _project_qkv(params, x, cfg, pos, compute_dtype):
     if cfg.qk_norm:
         q = rmsnorm({"scale": params["q_scale"]}, q)
         k = rmsnorm({"scale": params["k_scale"]}, k)
-    sections = cfg.mrope_sections if cfg.mrope else None
-    q = apply_rope(q, pos, cfg.rope_theta, sections)
-    k = apply_rope(k, pos, cfg.rope_theta, sections)
+    if cfg.rope:
+        sections = cfg.mrope_sections if cfg.mrope else None
+        q = apply_rope(q, pos, cfg.rope_theta, sections)
+        k = apply_rope(k, pos, cfg.rope_theta, sections)
     q = logical_constraint(q, ("batch", "seq", "heads", "head_dim"))
     k = logical_constraint(k, ("batch", "seq", "kv_heads", "head_dim"))
     v = logical_constraint(v, ("batch", "seq", "kv_heads", "head_dim"))

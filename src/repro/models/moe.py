@@ -73,13 +73,15 @@ def moe_axes(cfg):
     }
 
 
-def _route(router_w, x_flat, k):
-    """Returns (gates (N, k), idx (N, k), aux_loss scalar)."""
+def _route(router_w, x_flat, k, normalize=True):
+    """Returns (gates (N, k), idx (N, k), aux_loss scalar): the top-k
+    softmax probabilities, divided by their sum when ``normalize``."""
     logits = jnp.einsum("nd,de->ne", x_flat.astype(jnp.float32),
                         router_w.astype(jnp.float32))
     probs = jax.nn.softmax(logits, axis=-1)
     gates, idx = jax.lax.top_k(probs, k)
-    gates = gates / jnp.maximum(jnp.sum(gates, -1, keepdims=True), 1e-9)
+    if normalize:
+        gates = gates / jnp.maximum(jnp.sum(gates, -1, keepdims=True), 1e-9)
     # GShard aux loss: E * mean_e(frac_tokens_e * mean_prob_e)
     E = probs.shape[-1]
     onehot = jax.nn.one_hot(idx[:, 0], E, dtype=jnp.float32)
@@ -134,7 +136,8 @@ def moe_apply(params, x, cfg, *, mode: str = "mem",
     x_flat = x.reshape(B * S, d)
     N = B * S
 
-    gates, idx, aux = _route(params["router"], x_flat, k)
+    gates, idx, aux = _route(params["router"], x_flat, k,
+                             cfg.moe.normalize_gates)
 
     if model_axis is None:
         M, rank, E_loc = 1, 0, E

@@ -6,6 +6,11 @@ log-depth ``associative_scan`` *within* each chunk while carrying the SSM
 state across chunks with ``lax.scan`` — the (B, S, d_inner, N) discretized
 tensors only ever exist one chunk at a time (VMEM-sized working set), which
 is the same blocking insight rethought for the HBM->VMEM hierarchy.
+
+Jamba's mixer (``cfg.ssm.inner_norms``) adds RMSNorms with learned gains on
+``dt``, ``B`` and ``C`` after ``x_proj``.  The projections run under the
+``mamba.proj`` named scope and the scan under ``mamba.scan``, so their
+device ops carry the scope in the HLO metadata.
 """
 
 from __future__ import annotations
@@ -15,7 +20,7 @@ import jax.numpy as jnp
 
 from repro.core.sharding import logical_constraint
 from repro.core.socket import mem_write
-from repro.models.layers import _he
+from repro.models.layers import _he, rmsnorm
 
 
 def chunked_linear_scan(a, b, h0, chunk):
@@ -66,7 +71,7 @@ def mamba_init(key, cfg, dtype=jnp.float32):
     Kw = cfg.ssm.conv_dim
     ks = jax.random.split(key, 6)
     A = jnp.tile(jnp.arange(1, N + 1, dtype=jnp.float32)[None], (di, 1))
-    return {
+    p = {
         "in_proj": _he(ks[0], (d, 2 * di), dtype),
         "conv_w": (jax.random.normal(ks[1], (di, Kw)) * (Kw ** -0.5)).astype(dtype),
         "conv_b": jnp.zeros((di,), dtype),
@@ -77,10 +82,14 @@ def mamba_init(key, cfg, dtype=jnp.float32):
         "D": jnp.ones((di,), dtype),
         "out_proj": _he(ks[4], (di, d), dtype, fan_in=di),
     }
+    if cfg.ssm.inner_norms:
+        p.update(dt_norm=jnp.ones((dtr,), dtype), B_norm=jnp.ones((N,), dtype),
+                 C_norm=jnp.ones((N,), dtype))
+    return p
 
 
 def mamba_axes(cfg):
-    return {
+    a = {
         "in_proj": ("w_fsdp", "state"),
         "conv_w": ("state", None),
         "conv_b": ("state",),
@@ -91,6 +100,9 @@ def mamba_axes(cfg):
         "D": ("state",),
         "out_proj": ("state", "w_fsdp"),
     }
+    if cfg.ssm.inner_norms:
+        a.update(dt_norm=(None,), B_norm=(None,), C_norm=(None,))
+    return a
 
 
 def _discretize(params, x_conv, cfg, compute_dtype):
@@ -101,6 +113,10 @@ def _discretize(params, x_conv, cfg, compute_dtype):
                      params["x_proj"].astype(compute_dtype),
                      preferred_element_type=jnp.float32)
     dt_lr, B_ssm, C_ssm = jnp.split(dbc, [dtr, dtr + N], axis=-1)
+    if cfg.ssm.inner_norms:
+        dt_lr = rmsnorm({"scale": params["dt_norm"]}, dt_lr)
+        B_ssm = rmsnorm({"scale": params["B_norm"]}, B_ssm)
+        C_ssm = rmsnorm({"scale": params["C_norm"]}, C_ssm)
     dt = jnp.einsum("bsr,rd->bsd", dt_lr.astype(compute_dtype),
                     params["dt_proj"].astype(compute_dtype),
                     preferred_element_type=jnp.float32)
@@ -120,15 +136,33 @@ def mamba_apply(params, x, cfg, state=None, *, chunk=128,
         state = {"h": jnp.zeros((B, di, N), jnp.float32),
                  "conv": jnp.zeros((B, Kw - 1, di), jnp.float32)}
 
-    xz = jnp.einsum("bsd,de->bse", x.astype(compute_dtype),
-                    params["in_proj"].astype(compute_dtype))
+    with jax.named_scope("mamba.proj"):
+        xz = jnp.einsum("bsd,de->bse", x.astype(compute_dtype),
+                        params["in_proj"].astype(compute_dtype))
     x_in, z = jnp.split(xz, 2, axis=-1)
     x_in = logical_constraint(x_in, ("batch", "seq", "state"))
     x_conv, conv_state = causal_conv1d(x_in, params["conv_w"], params["conv_b"],
                                        state["conv"])
     x_conv = jax.nn.silu(x_conv.astype(jnp.float32)).astype(compute_dtype)
 
-    dt, B_ssm, C_ssm = _discretize(params, x_conv, cfg, compute_dtype)
+    with jax.named_scope("mamba.proj"):
+        dt, B_ssm, C_ssm = _discretize(params, x_conv, cfg, compute_dtype)
+    with jax.named_scope("mamba.scan"):
+        y, h_last = _chunked_scan(params, dt, B_ssm, C_ssm, x_conv, z,
+                                  state["h"], chunk)
+    y = logical_constraint(y.astype(compute_dtype), ("batch", "seq", "state"))
+    with jax.named_scope("mamba.proj"):
+        out = jnp.einsum("bse,ed->bsd", y,
+                         params["out_proj"].astype(compute_dtype),
+                         preferred_element_type=jnp.float32).astype(x.dtype)
+    out = mem_write(out, "ssm_output", ("batch", "seq", "embed"))
+    return out, {"h": h_last, "conv": conv_state}
+
+
+def _chunked_scan(params, dt, B_ssm, C_ssm, x_conv, z, h0, chunk):
+    """The selective scan over (B, S, di) inputs, ``chunk`` tokens at a
+    time, with the D skip and the z gate: (y (B, S, di) f32, h_last)."""
+    B, S, di = dt.shape
     A = -jnp.exp(params["A_log"].astype(jnp.float32))   # (di, N)
     xf = x_conv.astype(jnp.float32)
 
@@ -154,40 +188,36 @@ def mamba_apply(params, x, cfg, state=None, *, chunk=128,
         return jnp.moveaxis(t.reshape(B, n, c, *t.shape[2:]), 1, 0)
 
     h_last, ys = jax.lax.scan(
-        body, state["h"],
+        body, h0,
         (split_chunks(dt), split_chunks(B_ssm), split_chunks(C_ssm),
          split_chunks(xf)))
     y = jnp.moveaxis(ys, 0, 1).reshape(B, S, di)
     y = y + params["D"].astype(jnp.float32) * xf
-    y = y * jax.nn.silu(z.astype(jnp.float32))
-    y = logical_constraint(y.astype(compute_dtype), ("batch", "seq", "state"))
-    out = jnp.einsum("bse,ed->bsd", y, params["out_proj"].astype(compute_dtype),
-                     preferred_element_type=jnp.float32).astype(x.dtype)
-    out = mem_write(out, "ssm_output", ("batch", "seq", "embed"))
-    return out, {"h": h_last, "conv": conv_state}
+    return y * jax.nn.silu(z.astype(jnp.float32)), h_last
 
 
 def mamba_decode_step(params, x, cfg, state, *, compute_dtype=jnp.bfloat16):
     """Single-token decode.  x: (B, 1, d).  O(1) state update."""
-    B, _, d = x.shape
-    di = cfg.ssm.expand * d
-    Kw = cfg.ssm.conv_dim
-    xz = jnp.einsum("bsd,de->bse", x.astype(compute_dtype),
-                    params["in_proj"].astype(compute_dtype))
+    with jax.named_scope("mamba.proj"):
+        xz = jnp.einsum("bsd,de->bse", x.astype(compute_dtype),
+                        params["in_proj"].astype(compute_dtype))
     x_in, z = jnp.split(xz, 2, axis=-1)
     x_conv, conv_state = causal_conv1d(x_in, params["conv_w"], params["conv_b"],
                                        state["conv"])
     x_conv = jax.nn.silu(x_conv.astype(jnp.float32)).astype(compute_dtype)
-    dt, B_ssm, C_ssm = _discretize(params, x_conv, cfg, compute_dtype)
-    A = -jnp.exp(params["A_log"].astype(jnp.float32))
-    xf = x_conv.astype(jnp.float32)
-    dA = jnp.exp(dt[:, 0, :, None] * A[None])                    # (B,di,N)
-    dBx = dt[:, 0, :, None] * B_ssm[:, 0, None, :] * xf[:, 0, :, None]
-    h = dA * state["h"] + dBx
-    y = jnp.einsum("bdn,bn->bd", h, C_ssm[:, 0])[:, None]
-    y = y + params["D"].astype(jnp.float32) * xf
-    y = y * jax.nn.silu(z.astype(jnp.float32))
-    out = jnp.einsum("bse,ed->bsd", y.astype(compute_dtype),
-                     params["out_proj"].astype(compute_dtype),
-                     preferred_element_type=jnp.float32).astype(x.dtype)
+    with jax.named_scope("mamba.proj"):
+        dt, B_ssm, C_ssm = _discretize(params, x_conv, cfg, compute_dtype)
+    with jax.named_scope("mamba.scan"):
+        A = -jnp.exp(params["A_log"].astype(jnp.float32))
+        xf = x_conv.astype(jnp.float32)
+        dA = jnp.exp(dt[:, 0, :, None] * A[None])                # (B,di,N)
+        dBx = dt[:, 0, :, None] * B_ssm[:, 0, None, :] * xf[:, 0, :, None]
+        h = dA * state["h"] + dBx
+        y = jnp.einsum("bdn,bn->bd", h, C_ssm[:, 0])[:, None]
+        y = y + params["D"].astype(jnp.float32) * xf
+        y = y * jax.nn.silu(z.astype(jnp.float32))
+    with jax.named_scope("mamba.proj"):
+        out = jnp.einsum("bse,ed->bsd", y.astype(compute_dtype),
+                         params["out_proj"].astype(compute_dtype),
+                         preferred_element_type=jnp.float32).astype(x.dtype)
     return out, {"h": h, "conv": conv_state}

@@ -58,9 +58,10 @@ class RunFlags:
 # ------------------------------------------------------------- block defs ----
 
 def _ffn_kind(cfg: ArchConfig, kind: str, pos: int = 0) -> Optional[str]:
-    """pos = position within the repeating pattern (llama4 interleaves
-    dense and MoE FFNs via cfg.moe_pattern)."""
-    if kind == "mamba" or (cfg.d_ff == 0 and cfg.dense_ff == 0):
+    """pos = position within the repeating pattern (llama4 and Jamba
+    interleave dense and MoE FFNs via cfg.moe_pattern).  Every kind of
+    block takes one; none where the arch has no FFN width (Falcon-Mamba)."""
+    if cfg.d_ff == 0 and cfg.dense_ff == 0:
         return None
     if cfg.moe is not None and (cfg.moe_pattern is None or
                                 cfg.moe_pattern[pos % len(cfg.pattern)]):

@@ -155,9 +155,11 @@ class ServeEngine:
     (the argmax and its read-back) and ``engine.bookkeep`` (token append,
     eviction, block growth); and the counters ``admitted``, ``active``,
     ``n_slots``, ``kv_blocks_read`` (KV blocks the decode's attention
-    reads, summed over active slots) and ``kv_blocks_span`` (``n_slots *
-    max_blocks``, what a gather of every table row touches).  Without
-    one, nothing is recorded.
+    reads, summed over active slots), ``kv_blocks_span`` (``n_slots *
+    max_blocks``, what a gather of every table row touches) and
+    ``slot_state_bytes`` (the bytes of slot state, such as Mamba's ``h``
+    and conv tail, that the decode carries for the active slots; 0 where
+    every leaf is paged).  Without one, nothing is recorded.
     """
 
     def __init__(self, cfg: ArchConfig, *, prompt_len: int,
@@ -183,6 +185,7 @@ class ServeEngine:
                                       dtype=self.flags.cache_dtype)
         self.allocator = KB.BlockAllocator(1 + self.layout.capacity_blocks)
         self._in_place = RS.decodes_in_place(self.layout, mesh)
+        self._slot_bytes = KB.slot_state_bytes(self.layout)
         self.pools = KB.make_pools(self.layout)
         self.tables = KB.null_table(self.layout)
 
@@ -469,7 +472,8 @@ class ServeEngine:
                              n_slots=self.layout.n_slots,
                              kv_blocks_read=blocks_read,
                              kv_blocks_span=self.layout.n_slots *
-                             self.layout.max_blocks)
+                             self.layout.max_blocks,
+                             slot_state_bytes=len(active) * self._slot_bytes)
         self._end()
         return {"admitted": admitted, "active": len(active),
                 "evicted": evicted}

@@ -234,6 +234,14 @@ def paged_layout(cfg: ArchConfig, *, n_slots: int, prompt_len: int,
                        specs=specs)
 
 
+def slot_state_bytes(layout: PagedLayout) -> int:
+    """Bytes of one slot's row of every slot-state (not paged) leaf."""
+    return sum(int(np.prod(sp.contig_shape)) // layout.n_slots *
+               np.dtype(sp.dtype).itemsize
+               for sp in jax.tree.leaves(layout.specs, is_leaf=_spec_is_leaf)
+               if not sp.paged)
+
+
 # ------------------------------------------------------------ pool storage ----
 
 def _pool_shape(layout: PagedLayout, spec: LeafSpec) -> Tuple[int, ...]:

@@ -33,6 +33,7 @@ def test_full_config_matches_assignment():
         "recurrentgemma-9b": (38, 4096, 16, 1, 12288, 256000),
         "falcon-mamba-7b": (64, 4096, 0, 0, 0, 65024),
         "qwen2-vl-72b": (80, 8192, 64, 8, 29568, 152064),
+        "jamba2-mini": (32, 4096, 32, 8, 14336, 65536),
     }
     for name, (L, d, H, K, ff, V) in expect.items():
         c = get_config(name)
@@ -46,6 +47,27 @@ def test_moe_configs():
     assert get_config("llama4-maverick-400b-a17b").moe.n_experts == 128
     assert get_config("llama4-maverick-400b-a17b").moe.top_k == 1
     assert get_config("falcon-mamba-7b").ssm.state_dim == 16
+    jamba = get_config("jamba2-mini")
+    assert (jamba.moe.n_experts, jamba.moe.top_k) == (16, 2)
+    assert not jamba.moe.normalize_gates and not jamba.rope
+    assert jamba.ssm.inner_norms and jamba.ssm.dt_rank == 256
+
+
+def test_jamba_layer_order_is_transformers(monkeypatch):
+    """The layer kinds and the expert layers of the full Jamba2-Mini are
+    those ``transformers``' ``JambaConfig`` derives from the published
+    periods and offsets."""
+    monkeypatch.setenv("USE_TF", "0")
+    tr = pytest.importorskip("transformers")
+    cfg = get_config("jamba2-mini")
+    ref = tr.JambaConfig(num_hidden_layers=cfg.n_layers, attn_layer_period=8,
+                         attn_layer_offset=4, expert_layer_period=2,
+                         expert_layer_offset=1, num_experts=16)
+    kinds = ["attention" if k == "attn" else k for k in cfg.block_kinds()]
+    assert kinds == ref.layers_block_type
+    plen = len(cfg.pattern)
+    assert [16 if cfg.moe_pattern[i % plen] else 1
+            for i in range(cfg.n_layers)] == ref.layers_num_experts
 
 
 def test_param_counts_in_expected_range():
@@ -58,6 +80,7 @@ def test_param_counts_in_expected_range():
         "qwen2-vl-72b": (60e9, 85e9),
         "dbrx-132b": (110e9, 150e9),
         "llama4-maverick-400b-a17b": (330e9, 460e9),
+        "jamba2-mini": (50e9, 54e9),
     }
     for name, (lo, hi) in ranges.items():
         n = get_config(name).param_count()
@@ -65,6 +88,8 @@ def test_param_counts_in_expected_range():
     # active params for MoE archs are far below total
     l4 = get_config("llama4-maverick-400b-a17b")
     assert l4.active_param_count() < 0.15 * l4.param_count()
+    # Jamba2-Mini: "52B-A12B"; every Mamba layer has its feed-forward
+    assert 11.5e9 < get_config("jamba2-mini").active_param_count() < 12.5e9
 
 
 def test_long500k_applicability():

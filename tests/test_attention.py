@@ -1,6 +1,8 @@
 """Attention equivalences: flash == blockwise == full (values and grads),
 RoPE/M-RoPE, and the prefill->decode == forward integration contract."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -112,7 +114,8 @@ def test_mrope_sections_differ_from_plain_rope():
 
 
 @pytest.mark.parametrize("arch", ["qwen3-4b", "h2o-danube-3-4b", "olmo-1b",
-                                  "recurrentgemma-9b", "falcon-mamba-7b"])
+                                  "recurrentgemma-9b", "falcon-mamba-7b",
+                                  "jamba2-mini"])
 def test_prefill_then_decode_matches_forward(arch):
     """Integration contract: prefill(tokens[:-1]) + decode(tokens[-1])
     produces the same next-token logits as prefill(tokens)."""
@@ -137,3 +140,20 @@ def test_prefill_then_decode_matches_forward(arch):
     logits_dec, _ = T.decode_step(params, toks[:, -1:],
                                   jnp.int32(S - 1), caches, cfg, flags)
     np.testing.assert_allclose(logits_dec, logits_full, rtol=3e-2, atol=3e-2)
+
+
+def test_no_rope_projects_q_and_k_the_same_at_every_position():
+    """``cfg.rope`` off (Jamba): q and k do not depend on the position, on
+    the prefill path and the decode path alike; on, they do."""
+    from repro.models.attention import _project_qkv, attn_init
+    cfg = get_reduced("jamba2-mini")
+    params = attn_init(jax.random.key(0), cfg)
+    x = jax.random.normal(jax.random.key(1), (2, 1, cfg.d_model))
+    for rope in (False, True):
+        c = dataclasses.replace(cfg, rope=rope)
+        q0, k0, _ = _project_qkv(params, x, c, jnp.zeros((2, 1), jnp.int32),
+                                 jnp.float32)
+        q9, k9, _ = _project_qkv(params, x, c, jnp.full((2, 1), 9),
+                                 jnp.float32)
+        same = np.allclose(q0, q9) and np.allclose(k0, k9)
+        assert same is (not rope)

@@ -112,6 +112,25 @@ def test_kv_block_counters_equal_what_the_tables_imply():
     assert sum(st["kv_blocks_read"] for st in steps) > 0
 
 
+def test_slot_state_bytes_counts_the_active_slots_recurrent_state(served):
+    """``slot_state_bytes``: the float32 Mamba ``h`` (d_inner x N) and conv
+    tail ((d_conv - 1) x d_inner) of every Mamba layer, for each active
+    slot (Jamba); 0 where every leaf is paged KV (qwen3)."""
+    _, ex, _ = served
+    assert all(st["slot_state_bytes"] == 0 for st in ex["steps"])
+    cfg = get_reduced("jamba2-mini")
+    tr = EngineTrace()
+    infos = _serve(ServeEngine(cfg, prompt_len=8, max_new_tokens=4,
+                               n_slots=2, block_size=4, trace=tr))
+    di = cfg.ssm.expand * cfg.d_model
+    per_slot = cfg.block_kinds().count("mamba") * 4 * (
+        di * cfg.ssm.state_dim + (cfg.ssm.conv_dim - 1) * di)
+    steps = tr.export()["steps"]
+    assert [st["slot_state_bytes"] for st in steps] == \
+        [info["active"] * per_slot for info in infos]
+    assert any(st["slot_state_bytes"] for st in steps)
+
+
 def test_one_admit_span_per_admitted_request(served):
     eng, ex, infos = served
     admits = [s for s in ex["spans"] if s.name == "engine.admit"]

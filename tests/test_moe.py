@@ -62,6 +62,23 @@ def test_router_topk_normalized():
         assert len(set(row.tolist())) == cfg.moe.top_k
 
 
+def test_router_keeps_raw_softmax_gates_without_normalize():
+    """Jamba's gates: the top-k softmax probabilities themselves, the same
+    experts as the normalized route, summing to less than 1."""
+    cfg, params = _setup()
+    x = jax.random.normal(jax.random.key(2), (32, cfg.d_model))
+    g_n, idx_n, _ = M._route(params["router"], x, cfg.moe.top_k)
+    g_r, idx_r, _ = M._route(params["router"], x, cfg.moe.top_k,
+                             normalize=False)
+    np.testing.assert_array_equal(idx_r, idx_n)
+    probs = jax.nn.softmax(x @ params["router"].astype(jnp.float32), -1)
+    np.testing.assert_allclose(
+        g_r, jnp.take_along_axis(probs, idx_r, -1), rtol=1e-5)
+    assert bool(jnp.all(jnp.sum(g_r, -1) < 1.0))
+    np.testing.assert_allclose(g_n, g_r / jnp.sum(g_r, -1, keepdims=True),
+                               rtol=1e-5)
+
+
 @settings(deadline=None, max_examples=10)
 @given(seed=st.integers(0, 100))
 def test_capacity_drops_lowest_gates(seed):

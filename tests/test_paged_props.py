@@ -136,6 +136,13 @@ def test_recurrent_state_case_mamba():
                                     list(reversed(range(_PERM_WIDTH))))
 
 
+def test_hybrid_case_jamba():
+    # paged KV for the attention layer beside Mamba slot state in one
+    # layout: the gather/scatter step carries both
+    _assert_paged_equals_contiguous("jamba2-mini", S, 4, 8,
+                                    list(range(_PERM_WIDTH)))
+
+
 def test_in_place_step_crosses_a_block_boundary_with_donated_pools():
     # the prompt fills exactly three blocks of 4: the first decode opens
     # a fresh block and the fifth opens another, each written in place

@@ -1,5 +1,7 @@
 """SSM / RG-LRU recurrences vs. naive sequential oracles."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -103,3 +105,50 @@ def test_rglru_decay_in_unit_interval():
     a, _ = _gates_and_decay(params, u, jnp.bfloat16)
     assert float(jnp.min(a)) > 0.0
     assert float(jnp.max(a)) < 1.0
+
+
+def test_inner_norms_make_dt_b_c_scale_free():
+    """Jamba's RMSNorms on dt, B and C after ``x_proj``: scaling ``x_proj``
+    leaves the mixer's output as it was (up to the norms' eps); without
+    the norms it does not."""
+    cfg = get_reduced("jamba2-mini")
+    params = mamba_init(jax.random.key(0), cfg)
+    assert {"dt_norm", "B_norm", "C_norm"} <= set(params)
+    x = jax.random.normal(jax.random.key(1), (2, 16, cfg.d_model),
+                          jnp.float32)
+    scaled = dict(params, x_proj=params["x_proj"] * 7.0)
+    plain = dataclasses.replace(cfg, ssm=dataclasses.replace(
+        cfg.ssm, inner_norms=False))
+
+    @jax.jit
+    def outputs(params, scaled, x):
+        return [mamba_apply(p, x, c, chunk=8, compute_dtype=jnp.float32)[0]
+                for c in (cfg, plain) for p in (scaled, params)]
+
+    normed_s, normed, plain_s, plain_y = outputs(params, scaled, x)
+    np.testing.assert_allclose(normed_s, normed, rtol=1e-4, atol=1e-5)
+    assert not np.allclose(plain_s, plain_y, rtol=1e-2, atol=1e-2)
+
+
+def test_jamba_mamba_decode_matches_full():
+    """With the inner norms, token-at-a-time decode equals the full
+    sequence pass."""
+    cfg = get_reduced("jamba2-mini")
+    params = mamba_init(jax.random.key(0), cfg)
+    B, S = 1, 8
+    x = jax.random.normal(jax.random.key(1), (B, S, cfg.d_model),
+                          jnp.float32)
+    y_full, st_full = mamba_apply(params, x, cfg, chunk=4,
+                                  compute_dtype=jnp.float32)
+    di = cfg.ssm.expand * cfg.d_model
+    state = {"h": jnp.zeros((B, di, cfg.ssm.state_dim), jnp.float32),
+             "conv": jnp.zeros((B, cfg.ssm.conv_dim - 1, di), jnp.float32)}
+    outs = []
+    for t in range(S):
+        y_t, state = mamba_decode_step(params, x[:, t:t + 1], cfg, state,
+                                       compute_dtype=jnp.float32)
+        outs.append(y_t)
+    np.testing.assert_allclose(jnp.concatenate(outs, axis=1), y_full,
+                               rtol=1e-4, atol=1e-5)
+    np.testing.assert_allclose(state["h"], st_full["h"], rtol=1e-4,
+                               atol=1e-5)
