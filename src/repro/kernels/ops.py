@@ -20,6 +20,8 @@ from repro.kernels.multicast_stream import multicast_stream_local
 from repro.kernels.dma_double_buffer import dma_double_buffer_stream
 # decode attention over paged KV, read in place through the block table
 from repro.kernels.paged_attention import paged_decode_attention  # noqa: F401
+# the Mamba-1 selective scan with the state held in VMEM
+from repro.kernels.selective_scan import selective_scan  # noqa: F401
 
 
 def interpret_params():

@@ -1,11 +1,12 @@
 """Mamba-1 selective-scan block (Falcon-Mamba), TPU-adapted.
 
-The CUDA selective-scan kernel fuses the recurrence in SRAM.  The TPU-native
-adaptation chunks the sequence (``chunk`` tokens at a time) and runs a
-log-depth ``associative_scan`` *within* each chunk while carrying the SSM
-state across chunks with ``lax.scan`` — the (B, S, d_inner, N) discretized
-tensors only ever exist one chunk at a time (VMEM-sized working set), which
-is the same blocking insight rethought for the HBM->VMEM hierarchy.
+The CUDA selective-scan kernel fuses the recurrence in SRAM.  On a TPU the
+full-sequence scan is the Pallas kernel ``kernels/selective_scan.py``,
+which holds the state in VMEM and reads dt, B, C, x and z once.  Elsewhere
+(and as the kernel's backward) the XLA scan chunks the sequence (``chunk``
+tokens at a time) and runs a log-depth ``associative_scan`` *within* each
+chunk while carrying the SSM state across chunks with ``lax.scan``: the
+(B, S, d_inner, N) discretized tensors only ever exist one chunk at a time.
 
 Jamba's mixer (``cfg.ssm.inner_norms``) adds RMSNorms with learned gains on
 ``dt``, ``B`` and ``C`` after ``x_proj``.  The projections run under the
@@ -15,11 +16,17 @@ device ops carry the scope in the HLO metadata.
 
 from __future__ import annotations
 
+import functools
+import math
+
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
-from repro.core.sharding import logical_constraint
+from repro.core.sharding import (current_mesh, logical_constraint,
+                                 logical_to_pspec)
 from repro.core.socket import mem_write
+from repro.kernels import ops
 from repro.models.layers import _he, rmsnorm
 
 
@@ -148,8 +155,14 @@ def mamba_apply(params, x, cfg, state=None, *, chunk=128,
     with jax.named_scope("mamba.proj"):
         dt, B_ssm, C_ssm = _discretize(params, x_conv, cfg, compute_dtype)
     with jax.named_scope("mamba.scan"):
-        y, h_last = _chunked_scan(params, dt, B_ssm, C_ssm, x_conv, z,
-                                  state["h"], chunk)
+        plan = _kernel_plan(dt.shape)
+        if plan is None:
+            y, h_last = _chunked_scan(params, dt, B_ssm, C_ssm, x_conv, z,
+                                      state["h"], chunk)
+        else:
+            y, h_last = _kernel_scan(chunk, plan, params["A_log"],
+                                     params["D"], dt, B_ssm, C_ssm, x_conv,
+                                     z, state["h"])
     y = logical_constraint(y.astype(compute_dtype), ("batch", "seq", "state"))
     with jax.named_scope("mamba.proj"):
         out = jnp.einsum("bse,ed->bsd", y,
@@ -194,6 +207,61 @@ def _chunked_scan(params, dt, B_ssm, C_ssm, x_conv, z, h0, chunk):
     y = jnp.moveaxis(ys, 0, 1).reshape(B, S, di)
     y = y + params["D"].astype(jnp.float32) * xf
     return y * jax.nn.silu(z.astype(jnp.float32)), h_last
+
+
+def _kernel_plan(shape):
+    """Where the Pallas scan runs for (B, S, di) inputs: on a TPU, with
+    each shard's channels a whole number of 128 lanes and the sequence
+    whole on every shard.  Returns the mesh (None without one) and the
+    shard_map specs of (activations, B and C, channels, state), or None
+    where the XLA scan runs."""
+    if jax.default_backend() != "tpu":
+        return None
+    mesh = current_mesh()
+    act = logical_to_pspec(("batch", "seq", "state"), mesh=mesh, shape=shape)
+    axes = act[2] if isinstance(act[2], tuple) else (act[2],)
+    shards = math.prod(mesh.shape[a] for a in axes if a is not None) \
+        if mesh is not None else 1
+    if act[1] is not None or (shape[2] // shards) % 128:
+        return None
+    return mesh, (act, P(act[0], None, None), P(act[2], None),
+                  P(act[0], act[2], None))
+
+
+def _kernel_call(plan, A_log, D, dt, B_ssm, C_ssm, x, z, h0):
+    mesh, (act, bc, chan, st) = plan
+    if mesh is None:
+        return ops.selective_scan(dt, B_ssm, C_ssm, x, z, A_log, D, h0)
+    # the channels are independent: each shard scans its own, with no
+    # collective
+    return jax.shard_map(
+        ops.selective_scan, mesh=mesh,
+        in_specs=(act, bc, bc, act, act, chan, P(chan[0]), st),
+        out_specs=(act, st), check_vma=False)(
+            dt, B_ssm, C_ssm, x, z, A_log, D, h0)
+
+
+def _xla_scan(chunk, A_log, D, dt, B_ssm, C_ssm, x, z, h0):
+    y, h_last = _chunked_scan({"A_log": A_log, "D": D}, dt, B_ssm, C_ssm,
+                              x, z, h0, chunk)
+    return y.astype(x.dtype), h_last
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _kernel_scan(chunk, plan, A_log, D, dt, B_ssm, C_ssm, x, z, h0):
+    """The Pallas scan forward; its gradients are the XLA scan's."""
+    return _kernel_call(plan, A_log, D, dt, B_ssm, C_ssm, x, z, h0)
+
+
+def _kernel_scan_fwd(chunk, plan, *args):
+    return _kernel_call(plan, *args), args
+
+
+def _kernel_scan_bwd(chunk, plan, args, g):
+    return jax.vjp(functools.partial(_xla_scan, chunk), *args)[1](g)
+
+
+_kernel_scan.defvjp(_kernel_scan_fwd, _kernel_scan_bwd)
 
 
 def mamba_decode_step(params, x, cfg, state, *, compute_dtype=jnp.bfloat16):

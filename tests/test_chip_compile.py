@@ -182,13 +182,22 @@ def _kernel_programs(smoke, one_chip, four_chips):
             single((36, 513, 16, 8, 128)), single((36, 513, 16, 8, 128)),
             single((8, 64), jnp.int32), single((8,), jnp.int32),
             single((), jnp.int32)),
+        # one jamba2-mini Mamba layer's shard: 2 x 4096 tokens, 2048 of
+        # the 8192 channels, 16 states
+        "selective_scan": (
+            ops.selective_scan, single((2, 4096, 2048), jnp.float32),
+            single((2, 4096, 16), jnp.float32),
+            single((2, 4096, 16), jnp.float32), single((2, 4096, 2048)),
+            single((2, 4096, 2048)), single((2048, 16), jnp.float32),
+            single((2048,), jnp.float32),
+            single((2, 2048, 16), jnp.float32)),
     }
 
 
 @pytest.mark.parametrize("name", [
     "allgather_matmul", "reducescatter_matmul", "multicast",
     "streamed_gather_matmul", "dma_double_buffer_stream",
-    "paged_decode_attention"])
+    "paged_decode_attention", "selective_scan"])
 def test_kernel_compiles_for_the_chip(smoke, one_chip, four_chips, name):
     fn, *args = _kernel_programs(smoke, one_chip, four_chips)[name]
     compiled = jax.jit(fn).lower(*args).compile()
@@ -229,10 +238,12 @@ def test_engine_decode_reads_the_pools_in_place(smoke, one_chip,
     assert _peak(compiled) < HBM_BYTES
 
 
-def test_jamba_prefill_on_four_chips(four_chips):
+def test_jamba_prefill_on_four_chips(smoke, four_chips, monkeypatch):
     """The ``jamba2-mini.prefill-ep4`` cell's step at published widths (one
-    period, layers 4-11; 2 prompts x 4096) under the plan's verdict: it
-    fits a chip, and the expert dispatch is an all-to-all."""
+    period, layers 4-11; 2 prompts x 4096) under the plan's verdict, as the
+    chip runs it: it fits a chip, the expert dispatch is an all-to-all, each
+    of the seven Mamba layers scans in the Pallas kernel, and the kernel's
+    ``shard_map`` adds no collective to what the XLA scan's step holds."""
     from repro.configs import jamba2_mini
     from repro.configs.base import ShapeConfig
     from repro.core.planner import resolve_policy
@@ -249,9 +260,21 @@ def test_jamba_prefill_on_four_chips(four_chips):
     tokens = jax.ShapeDtypeStruct((B, S), jnp.int32, sharding=tok_sh)
     flags = T.RunFlags(compute_dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
                        remat="none", distributed=True, attn_impl="blockwise")
-    compiled = jax.jit(make_prefill_step(cfg, flags, four_chips, SERVE_RULES,
+
+    def compile_step():
+        return jax.jit(make_prefill_step(cfg, flags, four_chips, SERVE_RULES,
                                          plan),
                        in_shardings=(param_sh, tok_sh)).lower(
-        params, tokens).compile()
+            params, tokens).compile()
+
+    xla_text = compile_step().as_text()
+    # the step picks the scan by the backend it runs on; this process runs
+    # on the CPU and compiles for the described chip
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = compile_step()
+    text = compiled.as_text()
     assert _peak(compiled) < HBM_BYTES
-    assert "all-to-all" in compiled.as_text()
+    assert "all-to-all" in text
+    assert text.count('custom_call_target="tpu_custom_call"') == 7
+    assert "mamba_selective_scan" in text
+    assert smoke.collective_counts(text) == smoke.collective_counts(xla_text)
